@@ -163,6 +163,7 @@ def _round_level_bytes() -> Dict:
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"  # the child must not reach for the TPU
     with tempfile.TemporaryDirectory() as td:
         tmp = os.path.join(td, "round_audit.json")
         r = subprocess.run(

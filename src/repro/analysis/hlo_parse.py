@@ -39,8 +39,11 @@ DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# a collective-permute's ``source_target_pairs`` read as one two-device
+# group per pair: the pair crosses pods exactly when the group does
 _RG_LITERAL_RE = re.compile(
-    r"replica_groups=\{(\{[\d,]*\}(?:,\{[\d,]*\})*)\}")
+    r"(?:replica_groups|source_target_pairs)="
+    r"\{(\{[\d,]*\}(?:,\{[\d,]*\})*)\}")
 _RG_IOTA_RE = re.compile(
     r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
 _OP_RE = re.compile(
@@ -72,7 +75,9 @@ def _loop_read(operand_bytes: int, result_bytes: int, trips: int) -> float:
 
 def parse_replica_groups(attrs: str) -> Optional[List[List[int]]]:
     """Decode a collective's ``replica_groups`` attribute into device-id
-    groups.  Handles both emitted forms: the literal ``{{0,4},{1,5}}`` and
+    groups.  A collective-permute's ``source_target_pairs={{0,1},{1,0}}``
+    reads as one group per pair.  Handles both emitted forms: the literal
+    ``{{0,4},{1,5}}`` and
     the iota ``[4,2]<=[2,4]T(1,0)`` (reshape an arange to the ``<=[dims]``
     shape, transpose by the ``T`` permutation, flatten row-major, split
     into the ``[groups, group_size]`` rows).  Degenerate iota dims — size-1

@@ -69,7 +69,7 @@ def iter_pallas_eqns(jaxpr) -> List[Any]:
 
 
 def _sub_jaxprs(param: Any):
-    from jax.core import Jaxpr, ClosedJaxpr
+    from jax.extend.core import Jaxpr, ClosedJaxpr
     if isinstance(param, ClosedJaxpr):
         yield param.jaxpr
     elif isinstance(param, Jaxpr):
@@ -77,6 +77,17 @@ def _sub_jaxprs(param: Any):
     elif isinstance(param, (tuple, list)):
         for p in param:
             yield from _sub_jaxprs(p)
+
+
+def _blocked_size(b: Any, full: int) -> int:
+    """One BlockSpec entry's block size: an int, or (jax >= 0.9) a
+    ``Blocked(block_size=...)`` object.  None / squeezed entries mean the
+    dim is not blocked, so the block spans the whole array dim."""
+    if isinstance(b, int):
+        return int(b)
+    if type(b).__name__ == "Blocked":
+        return int(b.block_size)
+    return full
 
 
 def _block_mappings(eqn) -> List[Any]:
@@ -100,14 +111,15 @@ class PallasTileLint(Rule):
     # -- BlockSpec / dtype checks ------------------------------------------
     def _lint_mapping(self, label: str, bm) -> List[Violation]:
         out: List[Violation] = []
-        sd = getattr(bm, "array_shape_dtype", None)
+        # jax >= 0.9 names the operand aval ``array_aval``
+        sd = (getattr(bm, "array_shape_dtype", None)
+              or getattr(bm, "array_aval", None))
         if sd is None:
             return out
         ashape = tuple(int(d) for d in sd.shape)
         dtype = str(sd.dtype)
         raw = tuple(getattr(bm, "block_shape", ()) or ())
-        # None / pl.squeezed entries mean the dim is not blocked
-        bshape = tuple(ashape[i] if not isinstance(b, int) else int(b)
+        bshape = tuple(_blocked_size(b, ashape[i])
                        for i, b in enumerate(raw)) if raw else ashape
         if len(bshape) != len(ashape):
             return out

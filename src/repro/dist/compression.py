@@ -31,7 +31,7 @@ whole-array layout of ``kernels/quantize.py`` for callers that want it.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -83,8 +83,13 @@ def dequantize_int8(q: jnp.ndarray, scales: jnp.ndarray, shape
 # Tree-level encode / error feedback
 # ---------------------------------------------------------------------------
 
-def encode_tree(tree: Tree, mode: str = "int8", error: Optional[Tree] = None,
-                rng=None, with_residual: bool = True
+def _format(mode: Union[str, WireFormat]) -> WireFormat:
+    return mode if isinstance(mode, WireFormat) else get_format(mode)
+
+
+def encode_tree(tree: Tree, mode: Union[str, WireFormat] = "int8",
+                error: Optional[Tree] = None, rng=None,
+                with_residual: bool = True
                 ) -> Tuple[Tree, Optional[Tree], Optional[Tree]]:
     """Encode a payload tree; returns ``(payloads, reconstructed, new_error)``.
 
@@ -101,8 +106,11 @@ def encode_tree(tree: Tree, mode: str = "int8", error: Optional[Tree] = None,
     ``(payloads, None, None)`` — the fused-merge path uses this when no
     error-feedback state is tracked, so no reconstructed fp32 tree is ever
     built, even eagerly.
+
+    ``mode`` is a registered format name, or a format the round pinned
+    with ``get_format(name, use_kernel=..., mesh=...)``.
     """
-    fmt = get_format(mode)
+    fmt = _format(mode)
     eff = tree if error is None else jax.tree.map(jnp.add, tree, error)
     leaves, treedef = jax.tree.flatten(eff)
     if fmt.stochastic and rng is None:
@@ -123,7 +131,8 @@ def encode_tree(tree: Tree, mode: str = "int8", error: Optional[Tree] = None,
             jax.tree.unflatten(treedef, err))
 
 
-def decode_tree(payloads: Tree, template: Tree, mode: str = "int8") -> Tree:
+def decode_tree(payloads: Tree, template: Tree,
+                mode: Union[str, WireFormat] = "int8") -> Tree:
     """Decode a payload tree back into ``template``'s structure/shapes.
 
     ``payloads`` is the per-leaf payload-dict tree :func:`encode_tree`
@@ -132,9 +141,9 @@ def decode_tree(payloads: Tree, template: Tree, mode: str = "int8") -> Tree:
     receiver side of the wire: decoding *gathered* payloads is
     value-identical to decoding them before the gather, which is what
     keeps the unplaced merge the bit-exactness oracle for the
-    payload-gather one.
+    payload-gather one.  ``mode`` as in :func:`encode_tree`.
     """
-    fmt = get_format(mode)
+    fmt = _format(mode)
     leaves, treedef = jax.tree.flatten(template)
     p_leaves = treedef.flatten_up_to(payloads)
     return jax.tree.unflatten(

@@ -228,13 +228,19 @@ def _merge_sliced(w_global, payloads, delta, fmt, w1, w2, denom, any_push,
     return jax.tree.unflatten(treedef, out)
 
 
-def _merge_recv(w_global, recv, w1, w2, denom, any_push, use_kernel):
-    """The reconstructed-tree merge (uncompressed or decode-fallback path)."""
+def _merge_recv(w_global, recv, w1, w2, denom, any_push, use_kernel,
+                mesh=None):
+    """The reconstructed-tree merge (uncompressed or decode-fallback path).
+    On a multi-device ``mesh`` the kernel runs per device on the gathered
+    rows (``dist.wire.run_on_mesh``)."""
     if use_kernel:
+        from jax.sharding import PartitionSpec
+        from repro.dist.wire import run_on_mesh
         from repro.kernels import ops
+        merge = run_on_mesh(ops.loss_weighted_update, mesh,
+                            (PartitionSpec(),) * 6, PartitionSpec())
         return jax.tree.map(
-            lambda g, p: ops.loss_weighted_update(g, p, w1, w2, denom,
-                                                  any_push),
+            lambda g, p: merge(g, p, w1, w2, denom, any_push),
             w_global, recv)
     return jax.tree.map(
         lambda g, p: _merge_leaf_jnp(g, p, w1, w2, denom, any_push),
@@ -272,7 +278,9 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
       use_kernel: route the merge through the Pallas kernels — the fused
         dequant-merge kernel when the format has a ``fused_merge`` hook
         (the compressed payload flows through the merge directly), else the
-        fp32 loss-weighted-update kernel (identical math).
+        fp32 loss-weighted-update kernel (identical math).  The wire
+        format's own encode/decode kernels (the int4 nibble pack) follow
+        the same flag.
       rng:        PRNG key for stochastic formats (int4); fold per round.
       track_error: compute and return the error-feedback residual.  With
         ``track_error=False`` on the fused-kernel path the payloads are
@@ -315,7 +323,8 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
         return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
 
     if compression != "none":
-        fmt = get_format(compression)
+        fmt = get_format(compression, use_kernel=use_kernel, mesh=mesh,
+                         axis=pod_axis)
         fused = use_kernel and fmt.fused_merge is not None
         delta = jax.tree.map(
             lambda p, g: _gate_zero(p - g[None]), pod_params, w_global)
@@ -326,8 +335,7 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
         # dropped, so it never crosses the pod axis.  The decode-side
         # reconstruction is only built when the residual consumes it.
         payloads, _, residual = encode_tree(
-            delta, compression, error=err_in, rng=rng,
-            with_residual=track_error)
+            delta, fmt, error=err_in, rng=rng, with_residual=track_error)
         if not track_error:
             new_error = None
         elif error is None:
@@ -364,11 +372,11 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
         elif use_kernel:
             # Kernel merge wants the stacked reconstruction; pin it
             # pod-replicated so GSPMD cannot re-shard the decode.
-            rec = decode_tree(payloads, delta, compression)
+            rec = decode_tree(payloads, delta, fmt)
             rec = pin_gathered(rec, mesh, axis=pod_axis, n_pods=n_pods)
             recv = jax.tree.map(lambda g, d: g[None] + d, w_global, rec)
             new_global = _merge_recv(w_global, recv, w1, w2, denom,
-                                     any_push, use_kernel)
+                                     any_push, use_kernel, mesh)
         else:
             # Receiver-side: decode the *gathered* payloads row by row
             # and merge locally (see _merge_sliced for why slicewise).
@@ -381,7 +389,7 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
         recv = gather_payloads(recv, mesh, axis=pod_axis, n_pods=n_pods)
         new_error = error if track_error else None
         new_global = _merge_recv(w_global, recv, w1, w2, denom,
-                                 any_push, use_kernel)
+                                 any_push, use_kernel, mesh)
 
     # refresh: pushing pods restart from the merged global model
     new_pods = jax.tree.map(
@@ -559,6 +567,8 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
     compressed = cfg.compression != "none"
     track_error = cfg.error_feedback
     err_in = error if track_error else None
+    fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
+        getattr(cfg, "kernel_dispatch", "auto")), mesh=mesh, axis=pod_axis)
 
     def _gate_zero(leaf):
         return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
@@ -570,8 +580,7 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
                 lambda p, g: _gate_zero(p - g[None]), pods, wg)
             e_in = None if err is None else jax.tree.map(_gate_zero, err)
             payloads, _, residual = encode_tree(
-                delta, cfg.compression, error=e_in, rng=rng,
-                with_residual=track_error)
+                delta, fmt, error=e_in, rng=rng, with_residual=track_error)
             if not track_error:
                 new_error = None
             elif err is None:
@@ -603,8 +612,7 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
         # nothing).
         def _open(pods):
             recv = jax.tree.map(_gate_zero, pods)
-            payloads, _, _ = encode_tree(recv, cfg.compression,
-                                         with_residual=False)
+            payloads, _, _ = encode_tree(recv, fmt, with_residual=False)
             return gather_payloads(payloads, mesh, axis=pod_axis,
                                    n_pods=n_pods)
 
@@ -678,7 +686,8 @@ def hermes_commit(pod_params: Tree, pending: Dict[str, Any], w_global: Tree,
     def _open(args):
         pods, wg = args
         if compressed:
-            fmt = get_format(cfg.compression)
+            fmt = get_format(cfg.compression, use_kernel=use_kernel,
+                             mesh=mesh, axis=pod_axis)
             fused = use_kernel and fmt.fused_merge is not None
             # The merge machinery only reads shapes/dtypes from the delta
             # tree; the values stayed on the sender.  (A dead-at-commit
@@ -707,11 +716,11 @@ def hermes_commit(pod_params: Tree, pending: Dict[str, Any], w_global: Tree,
                     for g, p, dl in zip(g_leaves, p_leaves, d_leaves)]
                 new_global = jax.tree.unflatten(treedef, merged)
             elif use_kernel:
-                rec = decode_tree(payload, delta_t, cfg.compression)
+                rec = decode_tree(payload, delta_t, fmt)
                 rec = pin_gathered(rec, mesh, axis=pod_axis, n_pods=n_pods)
                 recv = jax.tree.map(lambda g, d: g[None] + d, wg, rec)
                 new_global = _merge_recv(wg, recv, w1, w2, denom,
-                                         any_push, use_kernel)
+                                         any_push, use_kernel, mesh)
             else:
                 new_global = _merge_sliced(wg, payload, delta_t, fmt,
                                            w1, w2, denom, any_push, n_pods)
@@ -724,7 +733,7 @@ def hermes_commit(pod_params: Tree, pending: Dict[str, Any], w_global: Tree,
                                                g.dtype), wg)
             recv = decode_tree(payload, rep_t, cfg.compression)
             new_global = _merge_recv(wg, recv, w1, w2, denom,
-                                     any_push, use_kernel)
+                                     any_push, use_kernel, mesh)
         new_pods = jax.tree.map(
             lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
             pods, new_global)
@@ -968,8 +977,8 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
                          live: Optional[jnp.ndarray] = None,
                          compression: str = "none",
                          error: Optional[Tree] = None, rng=None,
-                         track_error: bool = True, mesh=None,
-                         pod_axis: str = "pod",
+                         track_error: bool = True, use_kernel: bool = False,
+                         mesh=None, pod_axis: str = "pod",
                          cluster_axis: str = "cluster"
                          ) -> Tuple[Tree, Tree, Optional[Tree], jnp.ndarray]:
     """The two-tier gated loss-weighted merge (see the section comment).
@@ -989,6 +998,11 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
     single-tier round until the grid rebalances (``launch/elastic.py``).
     Lossy formats requantize at the cluster tier WITHOUT error feedback
     (deliberate; zero-mean for stochastic formats — DESIGN.md §10).
+    ``use_kernel`` pins only the wire format's encode/decode kernels (the
+    int4 nibble pack), and only unplaced: the tiered payloads are sharded
+    over two mesh tiers, for which no per-device kernel wrapper exists
+    yet, so a placed two-tier round packs with the exact jnp twin.  The
+    partial sums are jnp.
 
     Returns ``(new_pod_params, new_w_global, new_error, any_push)``.
     """
@@ -1012,14 +1026,13 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
     def _gate_zero(leaf):
         return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
 
-    fmt = get_format(compression)
+    fmt = get_format(compression, use_kernel=use_kernel and mesh is None)
     delta = jax.tree.map(
         lambda p, g: _gate_zero(p - g[None]), pod_params, w_global)
     if compression != "none":
         err_in = None if error is None else jax.tree.map(_gate_zero, error)
         payloads, _, residual = encode_tree(
-            delta, compression, error=err_in, rng=rng,
-            with_residual=track_error)
+            delta, fmt, error=err_in, rng=rng, with_residual=track_error)
         if not track_error:
             new_error = None
         elif error is None:
@@ -1033,7 +1046,7 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
         # replicas), the two-tier path ships the DELTA uniformly for all
         # formats — the partial-sum identity needs r_i, not w_i — and a
         # lossless wire drops nothing, so the residual passes through.
-        payloads, _, _ = encode_tree(delta, compression, with_residual=False)
+        payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
         new_error = error if track_error else None
 
     # Fast tier: every cluster gathers its own members' payload rows.
@@ -1052,7 +1065,7 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
     partials = jax.lax.optimization_barrier(partials)
     partials = pin_tier(partials, mesh, lead=cluster_axis, n_rows=C)
     crng = None if rng is None else jax.random.fold_in(rng, 0x5C1)
-    cpayloads, _, _ = encode_tree(partials, compression, rng=crng,
+    cpayloads, _, _ = encode_tree(partials, fmt, rng=crng,
                                   with_residual=False)
     # Barrier the wire bits too: in the dispatch/commit split the payload
     # is a cond output (a natural fusion boundary); pinning it here keeps
@@ -1089,11 +1102,15 @@ def hermes_cluster_round(pod_params: Tree, gup_state: Tree,
     ``cfg.n_clusters``; at an effective count of 1 this function is
     *literally* :func:`hermes_round` — the flat twin is called verbatim,
     so the ``n_clusters=1`` parity pin is bit-identity by construction.
-    ``use_kernel`` only reaches the flat path: the two-tier partials are
-    jnp-only (the fused/Pallas kernels keep serving the single-tier
-    merge).  Returns the same dict as ``hermes_round``.
+    ``use_kernel`` (``None``: ``cfg.kernel_dispatch``) picks the merge
+    kernels on the flat path; the two-tier partials are jnp-only, so
+    there it pins only the wire format's pack/unpack.  Returns the same
+    dict as ``hermes_round``.
     """
     C = resolve_n_clusters(cfg, n_clusters, cluster_sizes)
+    if use_kernel is None:
+        use_kernel = resolve_kernel_dispatch(
+            getattr(cfg, "kernel_dispatch", "auto"))
     if C <= 1:
         return hermes_round(pod_params, gup_state, pod_losses, w_global, L,
                             cfg, live=live, error=error,
@@ -1117,7 +1134,8 @@ def hermes_cluster_round(pod_params: Tree, gup_state: Tree,
             pods, gates, pod_losses, wg, L, n_clusters=C,
             cluster_sizes=cluster_sizes, compression=cfg.compression,
             error=err, rng=rng, track_error=cfg.error_feedback,
-            mesh=mesh, pod_axis=pod_axis, cluster_axis=cluster_axis)
+            use_kernel=use_kernel, mesh=mesh, pod_axis=pod_axis,
+            cluster_axis=cluster_axis)
         return new_pods, new_global, new_error
 
     def _closed(args):
@@ -1192,7 +1210,9 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
     w2 = jnp.where(gates,
                    1.0 / jnp.maximum(pod_losses.astype(jnp.float32), _EPS),
                    0.0)
-    fmt = get_format(cfg.compression)
+    # wire kernels only unplaced, as in hermes_cluster_merge
+    fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
+        getattr(cfg, "kernel_dispatch", "auto")) and mesh is None)
 
     def _gate_zero(leaf):
         return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
@@ -1204,8 +1224,7 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
         if compressed:
             e_in = None if err is None else jax.tree.map(_gate_zero, err)
             payloads, _, residual = encode_tree(
-                delta, cfg.compression, error=e_in, rng=rng,
-                with_residual=track_error)
+                delta, fmt, error=e_in, rng=rng, with_residual=track_error)
             if not track_error:
                 new_error = None
             elif err is None:
@@ -1215,8 +1234,7 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
                     lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
                     residual, err)
         else:
-            payloads, _, _ = encode_tree(delta, cfg.compression,
-                                         with_residual=False)
+            payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
             new_error = err
         shipped = gather_payloads_tiered(payloads, mesh, axis=pod_axis,
                                          keep=cluster_axis, n_rows=n_pods)
@@ -1228,7 +1246,7 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
         partials = jax.lax.optimization_barrier(partials)
         partials = pin_tier(partials, mesh, lead=cluster_axis, n_rows=C)
         crng = None if rng is None else jax.random.fold_in(rng, 0x5C1)
-        cpayloads, _, _ = encode_tree(partials, cfg.compression, rng=crng,
+        cpayloads, _, _ = encode_tree(partials, fmt, rng=crng,
                                       with_residual=False)
         cpayloads = jax.lax.optimization_barrier(cpayloads)
         cpayloads = gather_payloads(cpayloads, mesh, axis=cluster_axis,
@@ -1314,7 +1332,8 @@ def hermes_cluster_commit(pod_params: Tree, pending: Dict[str, Any],
                    1.0 / jnp.maximum(losses, _EPS), 0.0)
     denom = w1 + jnp.sum(w2)
     payload = _mask_cluster_rows(pending["cluster_payload"], keep_c, C)
-    fmt = get_format(cfg.compression)
+    fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
+        getattr(cfg, "kernel_dispatch", "auto")) and mesh is None)
     stacked_t = jax.tree.map(
         lambda g: jax.ShapeDtypeStruct((C,) + tuple(g.shape), g.dtype),
         w_global)

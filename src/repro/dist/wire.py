@@ -39,6 +39,7 @@ pipeline (Level-A billing, Level-B merge, benchmarks) picks it up.
 """
 from __future__ import annotations
 
+import copy
 import math
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -78,6 +79,21 @@ def resolve_kernel_dispatch(policy: str = "auto") -> bool:
     if policy == "off":
         return False
     return jax.default_backend() == "tpu"
+
+
+def run_on_mesh(fn, mesh, in_specs, out_specs):
+    """``fn`` run by every device of ``mesh`` on its own block (a
+    ``shard_map`` over all mesh axes); ``fn`` itself off a mesh or on one
+    device.  A Pallas TPU kernel cannot be partitioned automatically, so
+    inside a multi-device program each kernel call goes through here:
+    sender-side kernels with ``PartitionSpec(pod_axis)`` (each pod packs
+    its own rows), receiver-side merges with ``PartitionSpec()`` (every
+    device merges the gathered payload, as the replicated program
+    would)."""
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _norm_shape(shape) -> Tuple[int, ...]:
@@ -150,6 +166,10 @@ class WireFormat:
     name: str = "?"
     lossy: bool = True
     stochastic: bool = False  # True -> ``encode`` consumes an rng key
+    # kernel dispatch pinned by ``with_kernels`` (None: resolve per call)
+    use_kernel: Optional[bool] = None
+    kernel_mesh = None      # with a mesh, kernels run per device
+    kernel_axis: str = "pod"
 
     def encode(self, x: jnp.ndarray, *, rng=None) -> Payload:
         raise NotImplementedError
@@ -208,6 +228,36 @@ class WireFormat:
     # dequantized delta.  ``None`` means the merge falls back to
     # decode + loss_weighted_update.
     fused_merge = None
+
+    def with_kernels(self, use_kernel: Optional[bool], mesh=None,
+                     axis: str = "pod") -> "WireFormat":
+        """This format with its kernel dispatch pinned to ``use_kernel``
+        (``None`` keeps ``resolve_kernel_dispatch()``), for a round placed
+        on ``mesh`` with pods stacked along ``axis``: on a multi-device
+        mesh every kernel call runs per device (:func:`run_on_mesh`)."""
+        if use_kernel is None and mesh is None:
+            return self
+        pinned = copy.copy(self)
+        pinned.use_kernel = None if use_kernel is None else bool(use_kernel)
+        pinned.kernel_mesh, pinned.kernel_axis = mesh, axis
+        return pinned
+
+    def _kernels(self) -> bool:
+        return (resolve_kernel_dispatch() if self.use_kernel is None
+                else self.use_kernel)
+
+    def _per_pod(self, fn):
+        """``fn`` (one array in, one out, pod-stacked) run sender-side:
+        each device on its own pods' rows."""
+        from jax.sharding import PartitionSpec
+        spec = PartitionSpec(self.kernel_axis)
+        return run_on_mesh(fn, self.kernel_mesh, spec, spec)
+
+    def _replicated(self, fn, n_args: int):
+        """``fn`` run receiver-side on gathered (replicated) operands."""
+        from jax.sharding import PartitionSpec
+        return run_on_mesh(fn, self.kernel_mesh,
+                           (PartitionSpec(),) * n_args, PartitionSpec())
 
 
 # ---------------------------------------------------------------------------
@@ -326,8 +376,10 @@ class BlockedIntFormat(WireFormat):
         from repro.kernels import ops
         n_pods = payload["q"].shape[0]
         ax = block_axis((n_pods,) + tuple(g.shape))
-        return ops.dequant_merge(g, payload["q"], payload["scales"],
-                                 w2, denom, any_push, axis=ax)
+        merge = self._replicated(
+            lambda *a: ops.dequant_merge(*a, axis=ax), 6)
+        return merge(g, payload["q"], payload["scales"], w2, denom,
+                     any_push)
 
 
 class Int8Format(BlockedIntFormat):
@@ -358,9 +410,12 @@ class Int4Format(BlockedIntFormat):
     axis ships ~0.5 B/element — the blocked axis carries
     ``(d//256)*128 + ceil((d%256)/2)`` wire bytes, which is what
     ``payload_bytes`` now measures.  Pack/unpack dispatch follows the
-    same policy as the merge kernels (``resolve_kernel_dispatch``:
-    ``REPRO_WIRE_KERNEL`` > config > backend probe) with exact jnp twins
-    on the fallback path; the fused merge consumes ``q_packed`` directly
+    same policy as the merge kernels: the round resolves
+    ``HermesConfig.kernel_dispatch`` once and pins it with
+    :meth:`with_kernels` (unpinned, ``resolve_kernel_dispatch()`` decides:
+    ``REPRO_WIRE_KERNEL`` > backend probe), with exact jnp twins on the
+    fallback path.  Pack and unpack run sender-side, on each pod's own
+    rows; the fused merge consumes ``q_packed`` directly
     (``ops.dequant_merge_packed``), so the unpacked int8 tree never lands
     in HBM either.
     """
@@ -392,9 +447,10 @@ class Int4Format(BlockedIntFormat):
         parts = []
         if nf:
             head = jax.lax.slice_in_dim(q, 0, nf * BLOCK, axis=ax)
-            if resolve_kernel_dispatch():
+            if self._kernels():
                 from repro.kernels import ops
-                parts.append(ops.pack_int4(head, axis=ax))
+                parts.append(self._per_pod(
+                    lambda h: ops.pack_int4(h, axis=ax))(head))
             else:
                 parts.append(ref.pack_nibbles_ref(head, axis=ax, block=BLOCK))
         if rem:
@@ -415,9 +471,10 @@ class Int4Format(BlockedIntFormat):
         parts = []
         if nf:
             head = jax.lax.slice_in_dim(packed, 0, nf * self.HALF, axis=ax)
-            if resolve_kernel_dispatch():
+            if self._kernels():
                 from repro.kernels import ops
-                parts.append(ops.unpack_int4(head, axis=ax))
+                parts.append(self._per_pod(
+                    lambda h: ops.unpack_int4(h, axis=ax))(head))
             else:
                 parts.append(ref.unpack_nibbles_ref(head, axis=ax,
                                                     block=BLOCK))
@@ -436,9 +493,10 @@ class Int4Format(BlockedIntFormat):
         from repro.kernels import ops
         n_pods = payload["q_packed"].shape[0]
         ax = block_axis((n_pods,) + tuple(g.shape))
-        return ops.dequant_merge_packed(g, payload["q_packed"],
-                                        payload["scales"], w2, denom,
-                                        any_push, axis=ax)
+        merge = self._replicated(
+            lambda *a: ops.dequant_merge_packed(*a, axis=ax), 6)
+        return merge(g, payload["q_packed"], payload["scales"], w2, denom,
+                     any_push)
 
 
 # ---------------------------------------------------------------------------
@@ -804,12 +862,17 @@ def register(fmt: WireFormat, *, overwrite: bool = False) -> WireFormat:
     return fmt
 
 
-def get_format(name: str) -> WireFormat:
+def get_format(name: str, *, use_kernel: Optional[bool] = None, mesh=None,
+               axis: str = "pod") -> WireFormat:
+    """The registered format ``name``, its kernel dispatch pinned by
+    :meth:`WireFormat.with_kernels` when ``use_kernel`` or ``mesh`` is
+    given."""
     try:
-        return _REGISTRY[name]
+        fmt = _REGISTRY[name]
     except KeyError:
         raise ValueError(f"unknown compression mode {name!r} "
                          f"(want one of {available_formats()})") from None
+    return fmt.with_kernels(use_kernel, mesh, axis)
 
 
 def available_formats() -> Tuple[str, ...]:

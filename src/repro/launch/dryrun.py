@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual host devices; never the TPU
+# The lines above MUST run before any jax import (jax locks the device
 # count at first init).  REPRO_DRYRUN_DEVICES overrides for local debugging.
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="

@@ -45,6 +45,7 @@ import os
 if os.environ.get("REPRO_ELASTIC_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_ELASTIC_DEVICES"])
+    os.environ["JAX_PLATFORMS"] = "cpu"  # virtual devices; never the TPU
 
 import json
 import tempfile

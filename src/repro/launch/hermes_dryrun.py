@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual host devices; never the TPU
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_DRYRUN_DEVICES"])

@@ -64,6 +64,9 @@ def make_pod_mesh(n_pods: int, *, n_clusters: int = 1,
     devs = jax.devices()
     if max_devices:
         devs = devs[:max_devices]
+    if len(devs) < n_pods:
+        raise ValueError(f"{len(devs)} devices cannot host {n_pods} pods "
+                         f"(one device per pod at least)")
     if n_clusters <= 1:
         shape = pod_mesh_shape(len(devs), n_pods)
         n = shape[0] * shape[1] * shape[2]

@@ -37,6 +37,7 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count="
                       + os.environ.get("REPRO_ROUND_AUDIT_DEVICES", "8"))
+os.environ["JAX_PLATFORMS"] = "cpu"  # virtual host devices; never the TPU
 
 import argparse
 import json

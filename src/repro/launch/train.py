@@ -11,8 +11,9 @@ Two modes:
   refresh.  Communication (the merge collective) only carries compressed
   payloads on rounds where a gate opens.
 
-CPU-scale presets keep this runnable in the container (examples/ use them);
-on a real pod the same functions jit under the production mesh.
+CPU-scale presets keep the tests fast; ``--preset <arch> --layers N`` runs
+a registry architecture at its published widths, cut only in depth.
+``--placed`` puts each pod on its own devices (``launch.mesh.make_pod_mesh``).
 
 Usage:
     python -m repro.launch.train --preset lm100m --steps 300
@@ -20,36 +21,45 @@ Usage:
     python -m repro.launch.train --preset lm100m --hermes --pods 4 \
         --clusters 2 --steps 300   # two-tier: intra-cluster merge, one
                                    # packed payload per cluster crosses
+    python -m repro.launch.train --preset phi3-mini-3.8b --layers 1 \
+        --hermes --pods 4 --placed --batch 1 --seq 2048
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 from functools import partial
-from typing import Any, Dict, Optional
+from pathlib import Path
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.config import (
     ModelConfig,
     HermesConfig,
     OptimizerConfig,
     FAMILY_DENSE,
+    replace,
 )
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.checkpoint import Checkpointer
 from repro.data.synthetic import make_lm_dataset
 from repro.dist.hermes_sync import (
     hermes_cluster_commit, hermes_cluster_dispatch, hermes_cluster_round,
     hermes_pod_state,
 )
+from repro.launch.mesh import make_pod_mesh
 from repro.models import init_lm, lm_loss
 from repro.optim import make_optimizer
 
 Tree = Any
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
 PRESETS: Dict[str, ModelConfig] = {}
 
@@ -58,6 +68,93 @@ PRESETS: Dict[str, ModelConfig] = {}
 # at log intervals or after the loop — never per round, so the dispatch
 # queue stays full (tests/test_perf_opts.py counts these calls).
 _host_fetch = jax.device_get
+
+
+def configure_compile_cache(root=REPO_ROOT) -> str:
+    """Keep JAX's persistent compilation cache at one fixed directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here; otherwise the cache is ``<root>/.jax_cache``, the
+    same path for every run in one checkout (the path is part of the cache
+    key).  Entry points call this at start-up, never at import.  Returns
+    the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(root).resolve() / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def pod_shardings(mesh):
+    """``(pod-stacked, replicated)`` shardings on ``mesh``.
+
+    A pod-stacked tree's leading axis splits over the pod tier(s) — the
+    ``(cluster, pod)`` pair on a two-tier mesh — so each pod's rows live
+    on that pod's own devices; the global model is replicated.
+    ``(None, None)`` when unplaced.
+    """
+    if mesh is None:
+        return None, None
+    lead = ("cluster", "pod") if mesh.axis_names[0] == "cluster" else "pod"
+    return (NamedSharding(mesh, PartitionSpec(lead)),
+            NamedSharding(mesh, PartitionSpec()))
+
+
+def pod_rows(tree: Tree) -> Dict[int, List[int]]:
+    """Device id -> the pod rows of a pod-stacked ``tree`` that device
+    holds (union over leaves of each shard's leading-axis slice).  Reads
+    shard metadata only; nothing moves to the host."""
+    rows: Dict[int, set] = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            rows.setdefault(shard.device.id, set()).update(
+                range(leaf.shape[0])[shard.index[0]])
+    return {d: sorted(r) for d, r in sorted(rows.items())}
+
+
+def make_pod_step(cfg: ModelConfig, optimizer, mesh=None):
+    """The jitted pod-stacked local step ``(pod_params, pod_opt, batches)
+    -> (pod_params, pod_opt, losses)``: one vmapped value-and-grad +
+    optimizer update per pod.  The params/opt state are donated (consumed
+    in place, halving the peak for the largest arrays).  With a ``mesh``
+    every output stays pod-sharded, so each pod's step runs on its own
+    devices and lowers with no cross-pod collective."""
+    def pod_step(pod_params, pod_opt, batches):
+        def one(params, opt, batch):
+            loss, grads = jax.value_and_grad(
+                lambda p: lm_loss(p, batch, cfg))(params)
+            p, o = optimizer.apply(params, grads, opt)
+            return p, o, loss
+        return jax.vmap(one)(pod_params, pod_opt, batches)
+
+    pod_sh, _ = pod_shardings(mesh)
+    placed = {} if pod_sh is None else {"out_shardings": pod_sh}
+    return jax.jit(pod_step, donate_argnums=(0, 1), **placed)
+
+
+def make_round_jit(hcfg: HermesConfig, mesh=None):
+    """The synchronous round as one executable:
+    ``(pod_params, gup, pod_losses, w_global, L, error, rng) -> dict``
+    (``hermes_cluster_round``'s).  The pod params and the error residual
+    are donated (the round returns their successors); the residual is a
+    tree exactly when the wire is lossy with error feedback, else None.
+    With a ``mesh`` the pod-stacked outputs stay pod-sharded and the
+    global model replicated."""
+    pod_sh, rep = pod_shardings(mesh)
+    has_error = hcfg.compression != "none" and hcfg.error_feedback
+    placed = {}
+    if mesh is not None:
+        placed["out_shardings"] = {
+            "pod_params": pod_sh, "w_global": rep, "gup": pod_sh,
+            "error": pod_sh if has_error else None,
+            "gates": rep, "any_push": rep}
+    return jax.jit(
+        lambda pod_params, gup, pod_losses, w_global, L, error, rng:
+        hermes_cluster_round(pod_params, gup, pod_losses, w_global, L,
+                             cfg=hcfg, error=error, rng=rng, mesh=mesh),
+        donate_argnums=(0, 5), **placed)
 
 
 def make_async_round_jits(hcfg: HermesConfig, mesh=None):
@@ -93,7 +190,14 @@ def make_async_round_jits(hcfg: HermesConfig, mesh=None):
     return dispatch_jit, commit_jit
 
 
-def _preset(name: str) -> ModelConfig:
+def _preset(name: str, layers: int = 0) -> ModelConfig:
+    """A CPU preset (``lm100m``, ``lmtiny``), or a registry arch: its smoke
+    config by default, with ``layers > 0`` its published config with only
+    ``num_layers`` replaced (every width as published)."""
+    if layers:
+        cfg = replace(get_config(name), num_layers=layers)
+        cfg.validate()
+        return cfg
     if name == "lm100m":
         return ModelConfig(
             name="lm100m", family=FAMILY_DENSE, num_layers=12, d_model=768,
@@ -153,11 +257,14 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
         return {"params": p, "opt": o, "step": state["step"] + 1}, loss
 
     losses = []
+    log_times = []  # (step, wall s since loop start), taken after a sync
     t0 = time.time()
     for i in range(start_step, steps):
         state, loss = step_fn(state, next(batches))
         losses.append(float(loss))
         if (i + 1) % log_every == 0:
+            jax.block_until_ready(state)
+            log_times.append((i + 1, time.time() - t0))
             print(f"step {i+1:5d} loss {np.mean(losses[-log_every:]):.4f} "
                   f"({(i + 1 - start_step) / (time.time() - t0):.2f} it/s)",
                   flush=True)
@@ -170,7 +277,8 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     return {"final_loss": float(np.mean(losses[-10:])) if losses
             else float("nan"),
             "first_loss": losses[0] if losses else float("nan"),
-            "steps": steps}
+            "last_loss": losses[-1] if losses else float("nan"),
+            "steps": steps, "log_times": log_times}
 
 
 def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
@@ -201,6 +309,16 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     consumed exactly once), and a final drain commit flushes the
     last in-flight payload after the loop so every dispatched round
     merges exactly once.
+
+    With a ``mesh``, every pod-stacked tree (params, optimizer state, GUP
+    state, error residuals, batches) is placed pod-sharded
+    (:func:`pod_shardings`) and the global model replicated, so each pod
+    trains on its own devices; the result then also carries ``pod_rows``
+    (per tree, which pod rows each device holds) and
+    ``w_global_replicated``.  ``log_times`` lists ``(step, wall seconds)``
+    at each log line, taken after the log fetch has waited on the step;
+    ``gates`` holds each round's per-pod gate row (0/1), and ``history``
+    each round's ``(step, mean pod loss, open gates)``.
     """
     rng = np.random.default_rng(seed)
     tokens = make_lm_dataset(batch * seq * 40 * pods + batch * seq + 2,
@@ -214,25 +332,27 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                                    np.random.default_rng(seed)))
 
     optimizer = make_optimizer(opt_cfg)
-    params0, _ = init_lm(cfg, jax.random.PRNGKey(seed))
-    pod_params = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (pods,) + x.shape).copy(), params0)
-    pod_opt = jax.vmap(optimizer.init)(pod_params)
-    w_global = params0
-    L_global = jnp.float32(1e9)
-    gup = hermes_pod_state(hcfg, pods)
-    error = None
+    pod_sh, rep_sh = pod_shardings(mesh)
 
-    # donate the stacked params/opt state: the previous round's buffers
-    # are consumed in place, halving the peak for the largest arrays
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def pod_step(pod_params, pod_opt, batches):
-        def one(params, opt, batch):
-            loss, grads = jax.value_and_grad(
-                lambda p: lm_loss(p, batch, cfg))(params)
-            p, o = optimizer.apply(params, grads, opt)
-            return p, o, loss
-        return jax.vmap(one)(pod_params, pod_opt, batches)
+    def place(tree, sharding):
+        return tree if sharding is None else jax.device_put(tree, sharding)
+
+    params0, _ = init_lm(cfg, jax.random.PRNGKey(seed))
+    pod_params = place(jax.tree.map(
+        lambda x: jnp.broadcast_to(x[None], (pods,) + x.shape).copy(),
+        params0), pod_sh)
+    pod_opt = place(jax.vmap(optimizer.init)(pod_params), pod_sh)
+    w_global = place(params0, rep_sh)
+    L_global = jnp.float32(1e9)
+    gup = place(hermes_pod_state(hcfg, pods), pod_sh)
+    # a lossy wire with error feedback carries a residual from the first
+    # round on: start it at zeros (what the first round would create) so
+    # the round compiles once, for one tree structure
+    error = None
+    if hcfg.compression != "none" and hcfg.error_feedback:
+        error = place(jax.tree.map(jnp.zeros_like, pod_params), pod_sh)
+
+    pod_step = make_pod_step(cfg, optimizer, mesh)
 
     @jax.jit
     def pod_eval(pod_params):
@@ -254,6 +374,8 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     async_rounds = bool(getattr(hcfg, "async_rounds", False))
     if async_rounds:
         dispatch_jit, commit_jit = make_async_round_jits(hcfg, mesh)
+    else:
+        round_jit = make_round_jit(hcfg, mesh)
 
     def _commit_pending(pod_params, w_global, L_global, pending, counters):
         merges_dev, committed_dev = counters
@@ -269,11 +391,12 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     dispatched_dev = jnp.int32(0)  # async accounting: opens shipped…
     committed_dev = jnp.int32(0)   # …and opens merged (equal after drain)
     pending = None                 # the in-flight round (async only)
+    log_times = []                 # (step, wall s since loop start)
     t0 = time.time()
     history_dev = []               # (step, device mean loss, device gates)
     for i in range(steps):
-        stacked = {k: jnp.stack([next(b)[k] for b in batch_iters])
-                   for k in ("tokens", "targets")}
+        stacked = place({k: jnp.stack([next(b)[k] for b in batch_iters])
+                         for k in ("tokens", "targets")}, pod_sh)
         pod_params, pod_opt, losses = pod_step(pod_params, pod_opt, stacked)
         if (i + 1) % hcfg.lam == 0 or i == 0:
             rounds += 1
@@ -295,20 +418,20 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                 dispatched_dev = (dispatched_dev
                                   + dp["any_push"].astype(jnp.int32))
                 history_dev.append((i + 1, jnp.mean(pod_losses),
-                                    jnp.sum(dp["gates"])))
+                                    dp["gates"]))
             else:
-                out = hermes_cluster_round(pod_params, gup, pod_losses,
-                                           w_global, L_global, cfg=hcfg,
-                                           error=error, rng=rng_i, mesh=mesh)
+                out = round_jit(pod_params, gup, pod_losses, w_global,
+                                L_global, error, rng_i)
                 pod_params, w_global = out["pod_params"], out["w_global"]
                 gup, error = out["gup"], out["error"]
                 L_global = eval_if_push(out["any_push"], w_global, L_global)
                 merges_dev = merges_dev + out["any_push"].astype(jnp.int32)
                 history_dev.append((i + 1, jnp.mean(pod_losses),
-                                    jnp.sum(out["gates"])))
+                                    out["gates"]))
         if (i + 1) % log_every == 0:
             pod_l, gl_l, m = _host_fetch((jnp.mean(losses), L_global,
                                           merges_dev))
+            log_times.append((i + 1, time.time() - t0))
             print(f"step {i+1:5d} pod-loss {float(pod_l):.4f} "
                   f"global-L {float(gl_l):.4f} merges={int(m)}/{rounds}",
                   flush=True)
@@ -326,31 +449,48 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     hist_loss = (jnp.stack([l for _, l, _ in history_dev])
                  if history_dev else jnp.zeros((0,)))
     hist_gates = (jnp.stack([g for _, _, g in history_dev])
-                  if history_dev else jnp.zeros((0,), jnp.int32))
+                  if history_dev else jnp.zeros((0, pods), bool))
     gl, pl, merges, dispatched, committed, hist_loss, hist_gates = \
         _host_fetch((eval_global(w_global), pod_eval(pod_params), merges_dev,
                      dispatched_dev, committed_dev, hist_loss, hist_gates))
     gl, merges = float(gl), int(merges)
     pl = [float(x) for x in pl]
     history = [(s, float(l), int(g))
-               for s, l, g in zip(hist_steps, hist_loss, hist_gates)]
-    return {"global_loss": gl, "merges": merges, "rounds": rounds,
-            "pod_losses": pl, "best_pod_loss": min(pl),
-            "history": history, "steps": steps,
-            "comm_fraction": merges / max(rounds, 1),
-            "async_rounds": async_rounds,
-            "dispatched": int(dispatched), "committed": int(committed),
-            "drained": pending is None}
+               for s, l, g in zip(hist_steps, hist_loss, hist_gates.sum(1))]
+    out = {"global_loss": gl, "merges": merges, "rounds": rounds,
+           "pod_losses": pl, "best_pod_loss": min(pl),
+           "history": history, "steps": steps,
+           "comm_fraction": merges / max(rounds, 1),
+           "async_rounds": async_rounds,
+           "dispatched": int(dispatched), "committed": int(committed),
+           "drained": pending is None, "log_times": log_times,
+           "gates": hist_gates.astype(int).tolist()}
+    if mesh is not None:
+        out["pod_rows"] = {"pod_params": pod_rows(pod_params),
+                           "pod_opt": pod_rows(pod_opt),
+                           "error": None if error is None else pod_rows(error)}
+        out["w_global_replicated"] = all(
+            shard.data.shape == leaf.shape
+            for leaf in jax.tree.leaves(w_global)
+            for shard in leaf.addressable_shards)
+    return out
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="lmtiny")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="run a registry --preset at its published widths "
+                         "cut to this many layers (0 = its smoke config)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--hermes", action="store_true")
     ap.add_argument("--pods", type=int, default=4)
+    ap.add_argument("--placed", action="store_true",
+                    help="place each pod on its own devices (a pod mesh over "
+                         "jax.devices(); fails when there are fewer devices "
+                         "than pods)")
     ap.add_argument("--clusters", type=int, default=1,
                     help="two-tier Hermes (DESIGN.md §10): group the pods "
                          "into N latency clusters; the gated merge runs "
@@ -382,7 +522,8 @@ def main() -> None:
     ap.add_argument("--restore", action="store_true")
     args = ap.parse_args()
 
-    cfg = _preset(args.preset)
+    configure_compile_cache()
+    cfg = _preset(args.preset, args.layers)
     opt = OptimizerConfig(name="adamw", lr=args.lr)
     if args.hermes:
         kw = {} if args.compression is None else {
@@ -396,9 +537,11 @@ def main() -> None:
         if args.clusters > 1 and args.pods % args.clusters:
             ap.error(f"--pods {args.pods} must split evenly into "
                      f"--clusters {args.clusters}")
+        mesh = (make_pod_mesh(args.pods, n_clusters=args.clusters)
+                if args.placed else None)
         out = train_hermes(cfg, steps=args.steps, batch=args.batch,
                            seq=args.seq, pods=args.pods, opt_cfg=opt,
-                           hcfg=hcfg, ckpt_dir=args.ckpt)
+                           hcfg=hcfg, ckpt_dir=args.ckpt, mesh=mesh)
         out["compression"] = hcfg.compression
     else:
         out = train_single(cfg, steps=args.steps, batch=args.batch,

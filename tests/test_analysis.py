@@ -81,6 +81,9 @@ def test_async_start_done_counted_once():
     # bare {} = "one group of all replicas": unparsable -> None
     ("replica_groups={}", None),
     ("no groups here at all", None),
+    # collective-permute: one group per (source, target) pair
+    ("channel_id=95, source_target_pairs={{0,0},{1,2},{2,1},{3,3}}",
+     [[0, 0], [1, 2], [2, 1], [3, 3]]),
 ])
 def test_replica_group_forms(attrs, expect):
     assert parse_replica_groups(attrs) == expect

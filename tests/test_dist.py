@@ -176,6 +176,30 @@ def test_kernel_path_exercised_on_cpu_via_env(monkeypatch):
     np.testing.assert_allclose(np.asarray(xr), np.asarray(x), atol=0.02)
 
 
+@pytest.mark.parametrize("policy,want_kernels", [("off", False),
+                                                 ("on", True),
+                                                 ("auto", True)])
+def test_round_wire_follows_kernel_dispatch(policy, want_kernels,
+                                            monkeypatch):
+    """The int4 nibble pack follows ``HermesConfig.kernel_dispatch`` like
+    the merge does: with the backend probe answering "tpu", ``off`` traces
+    a round (sync, and the dispatch half) with no Pallas call at all."""
+    from repro.dist.hermes_sync import (hermes_dispatch, hermes_pod_state,
+                                        hermes_round)
+    monkeypatch.delenv("REPRO_WIRE_KERNEL", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = HermesConfig(compression="int4", kernel_dispatch=policy)
+    pods = {"w": jnp.ones((2, 8, 512))}
+    wg = {"w": jnp.zeros((8, 512))}
+    args = (pods, hermes_pod_state(cfg, 2), jnp.array([1.0, 2.0]), wg,
+            jnp.float32(3.0))
+    for fn in (hermes_round, hermes_dispatch):
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a, fn=fn: fn(*a, cfg, rng=jax.random.PRNGKey(0)))(*args))
+        assert ("pallas_call" in jaxpr) == want_kernels, (fn.__name__,
+                                                          policy)
+
+
 def test_int4_stochastic_rounding_pinned():
     """Non-hypothesis twin of the test_properties int4 invariants, so they
     run even where hypothesis is unavailable: per-element error is bounded

@@ -145,7 +145,6 @@ def test_int4_stochastic_error_bounded_per_block(n, scale, seed):
     from repro.dist.wire import get_format
     rng = np.random.default_rng(n + seed)
     x = jnp.asarray(rng.normal(0, scale, n), jnp.float32)
-    from repro.kernels import ref
     fmt = get_format("int4")
     p = fmt.encode(x, rng=jax.random.PRNGKey(seed))
     xr = fmt.decode(p, x.shape, x.dtype)
@@ -154,8 +153,9 @@ def test_int4_stochastic_error_bounded_per_block(n, scale, seed):
     assert np.all(err <= step + 1e-6)
     # the wire array is nibble-packed; every unpacked nibble is int4
     assert p["q_packed"].dtype == jnp.int8
-    q = ref.unpack_nibbles_ref(p["q_packed"], axis=0)
-    assert q.shape[0] == 2 * p["q_packed"].shape[0]
+    assert p["q_packed"].shape == (fmt.packed_len(n),)
+    q = fmt.unpack_payload(p, x.shape)
+    assert q.shape == x.shape
     assert np.abs(np.asarray(q)).max() <= 7
 
 
