@@ -1,11 +1,6 @@
-"""Kernel microbenchmarks (framework layer, no paper table).
+"""The sub-byte wire path's byte counts and kernel smoke gate (ISSUE 5).
 
-Wall-clock on CPU for the jnp formulations (scan vs chunked vs blocked) —
-the *relative* numbers motivate the Pallas kernels; the kernels themselves
-are timed in interpret mode only for correctness, not speed (CPU container;
-TPU is the target).  Derived column = achieved GFLOP/s of the jnp path.
-
-Two extra modes for the sub-byte wire path (ISSUE 5):
+Kernel speed is measured on the chip (``bench/``); nothing here times.
 
 * ``--wire-bytes`` — per-format **measured** payload bytes at LM scale
   (the ``lm100m`` parameter tree via ``jax.eval_shape``, no allocation),
@@ -20,129 +15,10 @@ Two extra modes for the sub-byte wire path (ISSUE 5):
 from __future__ import annotations
 
 import math
-import time
-from typing import Dict, List
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
-
-from repro.models.attention import blocked_attention, naive_attention
-from repro.models.rwkv import wkv_scan, wkv_chunked
-from repro.models.rglru import lru_scan, lru_scan_sequential
-
-
-def _time(fn, *args, reps=3) -> float:
-    fn(*args)  # compile
-    t0 = time.time()
-    for _ in range(reps):
-        out = fn(*args)
-    jax.tree.leaves(out)[0].block_until_ready()
-    return (time.time() - t0) / reps * 1e6  # us
-
-
-def run(*, fast: bool = False) -> List[Dict]:
-    rows = []
-    B, S, H, D = (1, 512, 4, 32) if fast else (2, 1024, 8, 64)
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, S, H, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, S, H, D), jnp.float32)
-    attn_flops = 4.0 * B * H * S * S * D
-
-    f_naive = jax.jit(lambda q, k, v: naive_attention(q, k, v, causal=True))
-    f_block = jax.jit(lambda q, k, v: blocked_attention(q, k, v, causal=True,
-                                                        q_chunk=256,
-                                                        kv_chunk=256))
-    for name, fn in [("attn_naive", f_naive), ("attn_blocked_jnp", f_block)]:
-        us = _time(fn, q, k, v)
-        rows.append({"name": name, "us_per_call": round(us, 1),
-                     "derived": f"{attn_flops / us / 1e3:.1f}GFLOP/s"})
-
-    T = 512 if fast else 2048
-    Hh, Dd = 4, 32
-    r = jax.random.normal(ks[3], (B, T, Hh, Dd)) * 0.5
-    kk = jax.random.normal(ks[4], (B, T, Hh, Dd)) * 0.5
-    vv = jax.random.normal(ks[5], (B, T, Hh, Dd)) * 0.5
-    lw = -jnp.exp(jax.random.normal(ks[0], (B, T, Hh, Dd)) * 0.3 - 2.0)
-    u = jnp.zeros((Hh, Dd))
-    s0 = jnp.zeros((B, Hh, Dd, Dd))
-    wkv_flops = 4.0 * B * T * Hh * Dd * Dd
-    f_scan = jax.jit(lambda *a: wkv_scan(*a))
-    f_chunk = jax.jit(lambda *a: wkv_chunked(*a))
-    for name, fn in [("wkv6_scan", f_scan), ("wkv6_chunked", f_chunk)]:
-        us = _time(fn, r, kk, vv, lw, u, s0)
-        rows.append({"name": name, "us_per_call": round(us, 1),
-                     "derived": f"{wkv_flops / us / 1e3:.1f}GFLOP/s"})
-
-    W = 256 if fast else 1024
-    a = jax.nn.sigmoid(jax.random.normal(ks[1], (B, T, W))) * 0.2 + 0.8
-    b = jax.random.normal(ks[2], (B, T, W)) * 0.1
-    f_assoc = jax.jit(lambda a, b: lru_scan(a, b, None))
-    f_seq = jax.jit(lambda a, b: lru_scan_sequential(a, b, None))
-    for name, fn in [("rglru_assoc", f_assoc), ("rglru_seq", f_seq)]:
-        us = _time(fn, a, b)
-        rows.append({"name": name, "us_per_call": round(us, 1),
-                     "derived": f"{2.0 * B * T * W / us / 1e3:.1f}GFLOP/s"})
-    rows += run_wire(fast=fast)
-    return rows
-
-
-def run_wire(*, fast: bool = False) -> List[Dict]:
-    """The quantized wire path: encode / pack / fused-merge timings.
-
-    jnp formulations (the CPU fallback path), one LM-block-sized leaf;
-    derived column = effective wire GB/s (payload bytes produced or merged
-    per wall second) so the packed rows show the bytes halving directly.
-    """
-    from repro.dist.wire import block_axis, get_format
-    from repro.kernels import ref
-
-    rows: List[Dict] = []
-    n_pods = 2
-    shape = (768, 2048) if fast else (4096, 2048)
-    x = jax.random.normal(jax.random.PRNGKey(0), (n_pods,) + shape) * 0.1
-    ax = block_axis((n_pods,) + shape)
-    key = jax.random.PRNGKey(1)
-    for mode in ("int8", "int4"):
-        fmt = get_format(mode)
-        enc = jax.jit(lambda v, k, _f=fmt: _f.encode(v, rng=k))
-        us = _time(enc, x, key)
-        pb = sum(int(a.size) * a.dtype.itemsize
-                 for a in enc(x, key).values())
-        rows.append({"name": f"wire_encode_{mode}", "us_per_call": round(us, 1),
-                     "derived": f"{pb / us / 1e3:.2f}GB/s;payload={pb}B"})
-
-    q8 = get_format("int8").encode(x)["q"]
-    f_pack = jax.jit(lambda q: ref.pack_nibbles_ref(q, axis=ax))
-    packed = f_pack(q8)
-    us = _time(f_pack, q8)
-    rows.append({"name": "pack_nibbles_jnp", "us_per_call": round(us, 1),
-                 "derived": f"{packed.size / us / 1e3:.2f}GB/s(out)"})
-    f_unpack = jax.jit(lambda p: ref.unpack_nibbles_ref(p, axis=ax))
-    us = _time(f_unpack, packed)
-    rows.append({"name": "unpack_nibbles_jnp", "us_per_call": round(us, 1),
-                 "derived": f"{q8.size / us / 1e3:.2f}GB/s(out)"})
-
-    g = jax.random.normal(jax.random.PRNGKey(2), shape)
-    w2 = jnp.array([0.5, 1.25])
-    denom = 0.7 + float(w2.sum())
-    p4 = get_format("int4").encode(x, rng=key)
-    merged_bytes = p4["q_packed"].size + 4 * p4["scales"].size
-    f_ref = jax.jit(lambda g, q, s: ref.dequant_merge_packed_ref(
-        g, q, s, w2, denom, True, axis=ax))
-    us = _time(f_ref, g, p4["q_packed"], p4["scales"])
-    rows.append({"name": "dequant_merge_packed_jnp",
-                 "us_per_call": round(us, 1),
-                 "derived": f"{merged_bytes / us / 1e3:.2f}GB/s(payload)"})
-    q4 = ref.unpack_nibbles_ref(p4["q_packed"], axis=ax)
-    f_ref8 = jax.jit(lambda g, q, s: ref.dequant_merge_ref(
-        g, q, s, w2, denom, True, axis=ax))
-    us = _time(f_ref8, g, q4, p4["scales"])
-    gbs = (q4.size + 4 * p4["scales"].size) / us / 1e3
-    rows.append({"name": "dequant_merge_unpacked_jnp",
-                 "us_per_call": round(us, 1),
-                 "derived": f"{gbs:.2f}GB/s(payload)"})
-    return rows
 
 
 def _round_level_bytes() -> Dict:
@@ -287,18 +163,12 @@ if __name__ == "__main__":
     import json
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--wire-bytes", action="store_true",
-                    help="write results/bench/wire_path.json (measured "
-                         "per-format payload bytes at LM scale)")
-    ap.add_argument("--smoke", action="store_true",
-                    help="pack/unpack + packed-merge kernel correctness "
-                         "gate (interpret mode)")
-    ap.add_argument("--fast", action="store_true")
+    mode = ap.add_mutually_exclusive_group(required=True)
+    mode.add_argument("--wire-bytes", action="store_true",
+                      help="write results/bench/wire_path.json (measured "
+                           "per-format payload bytes at LM scale)")
+    mode.add_argument("--smoke", action="store_true",
+                      help="pack/unpack + packed-merge kernel correctness "
+                           "gate (interpret mode)")
     args = ap.parse_args()
-    if args.smoke:
-        print(json.dumps(smoke(), indent=2))
-    elif args.wire_bytes:
-        print(json.dumps(wire_bytes(), indent=2))
-    else:
-        for row in run(fast=args.fast):
-            print(f"{row['name']},{row['us_per_call']},{row['derived']}")
+    print(json.dumps(smoke() if args.smoke else wire_bytes(), indent=2))

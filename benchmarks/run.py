@@ -12,7 +12,6 @@ Suites:
     gup      — §V-D / Fig. 13 major-update trace
     alphabeta— §V-E / Fig. 14 sensitivity
     bsp      — Fig. 2/4/5 BSP breakdown
-    kernels  — kernel microbenchmarks
 """
 from __future__ import annotations
 
@@ -82,12 +81,6 @@ def bench_bsp(fast: bool) -> None:
              f"wait_frac={row['wait_fraction']}")
 
 
-def bench_kernels(fast: bool) -> None:
-    from benchmarks import kernel_bench as K
-    for r in K.run(fast=fast):
-        _row(f"kernels/{r['name']}", r["us_per_call"], r["derived"])
-
-
 SUITES = {
     "table3": bench_table3,
     "comm": bench_comm,
@@ -95,7 +88,6 @@ SUITES = {
     "gup": bench_gup,
     "alphabeta": bench_alphabeta,
     "bsp": bench_bsp,
-    "kernels": bench_kernels,
 }
 
 

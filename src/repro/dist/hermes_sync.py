@@ -47,6 +47,7 @@ from repro.dist.wire import (
     gather_payloads_tiered, payload_buffer_spec, pin_tier,
     resolve_kernel_dispatch,
 )
+from repro.utils.tracing import ENCODE, GATE, MERGE, REFRESH, scope
 
 Tree = Any
 
@@ -83,6 +84,14 @@ def hermes_grow_pod_state(gup_state: Tree, cfg: HermesConfig,
 def _pod_mask(gates: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
     """Reshape (n,) gates to broadcast against a (n, ...) stacked leaf."""
     return gates.reshape(gates.shape + (1,) * (leaf.ndim - 1))
+
+
+def _gate_zero(gates: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
+    """Zero the closed pods' rows of a stacked leaf.  Closed pods transmit
+    nothing: they are masked out of every wire and merge term, so a
+    diverged (nonfinite) replica cannot poison the global model through
+    its 0-weight contribution (0 * nan = nan)."""
+    return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
 
 
 def admit_gates(gates: jnp.ndarray, losses: jnp.ndarray, cfg: HermesConfig,
@@ -136,6 +145,82 @@ def admit_gates(gates: jnp.ndarray, losses: jnp.ndarray, cfg: HermesConfig,
     return gates & (rank < k)
 
 
+def _gate(gup_state: Tree, pod_losses: jnp.ndarray, cfg: HermesConfig,
+          live: Optional[jnp.ndarray], rng) -> Tuple[jnp.ndarray, Tree,
+                                                      jnp.ndarray]:
+    """Every round family's gate: ``(gates, new_gup, any_push)``.
+
+    The per-pod Algorithm-1 gates are the vmapped device twin of
+    ``core.gup.gup_update``; a dead pod's gate is forced shut (``live``).
+    The participation budget applies AFTER the gate+live mask and BEFORE
+    any_push / wire / merge / refresh: a deferred pod behaves exactly like
+    a closed one downstream (the per-pod GUP bookkeeping already advanced
+    on the RAW gate decision — deferral is a transport policy, not a gate
+    override).  At participation_rate=1.0 admission is ``gates`` itself.
+    """
+    with scope(GATE):
+        gates, new_gup = jax.vmap(
+            lambda s, x: gup_gate_jax(s, x, cfg))(gup_state, pod_losses)
+        gates = gates.astype(bool)
+        if live is not None:
+            gates = gates & live.astype(bool)
+        gates = admit_gates(gates, pod_losses, cfg, rng=rng)
+        return gates, new_gup, jnp.any(gates)
+
+
+def _merge_weights(gates: jnp.ndarray, losses: jnp.ndarray, L):
+    """Algorithm 2's merge weights ``(w1, w2, denom)``: ``1/L`` for the
+    global model, ``1/loss_i`` for each open pod (0 for closed ones), and
+    their sum."""
+    with scope(MERGE):
+        w1 = 1.0 / jnp.maximum(jnp.asarray(L, jnp.float32), _EPS)
+        w2 = jnp.where(gates,
+                       1.0 / jnp.maximum(losses.astype(jnp.float32), _EPS),
+                       0.0)
+        return w1, w2, w1 + jnp.sum(w2)
+
+
+def _encode(pods: Tree, w_global: Tree, gates: jnp.ndarray,
+            error: Optional[Tree], fmt, *, rng=None, track_error: bool = True,
+            lossy: bool = True) -> Tuple[Tree, Tree, Optional[Tree]]:
+    """Sender side of every round family: ``(delta, payloads, new_error)``.
+
+    Gate-open pods ship ``w_i - w_global``, encoded with their accumulated
+    error folded in (error feedback); closed pods' rows are zeros.  The
+    residual stays local — each pod's private bookkeeping of what its own
+    wire dropped — and a closed pod keeps its pending error.  A lossless
+    wire (``lossy=False``) drops nothing, so the residual passes through.
+    """
+    with scope(ENCODE):
+        delta = jax.tree.map(
+            lambda p, g: _gate_zero(gates, p - g[None]), pods, w_global)
+        if not lossy:
+            payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
+            return delta, payloads, error if track_error else None
+        err_in = (None if error is None
+                  else jax.tree.map(lambda e: _gate_zero(gates, e), error))
+        payloads, _, residual = encode_tree(
+            delta, fmt, error=err_in, rng=rng, with_residual=track_error)
+        if not track_error:
+            new_error = None
+        elif error is None:
+            new_error = jax.tree.map(lambda r: _gate_zero(gates, r),
+                                     residual)
+        else:
+            new_error = jax.tree.map(
+                lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
+                residual, error)
+        return delta, payloads, new_error
+
+
+def _refresh(pods: Tree, new_global: Tree, gates: jnp.ndarray) -> Tree:
+    """Pushing pods restart local training from the merged global model."""
+    with scope(REFRESH):
+        return jax.tree.map(
+            lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
+            pods, new_global)
+
+
 def _merge_leaf_jnp(g, pods, w1, w2, denom, any_push):
     """(w1*g + sum_i w2_i*pods_i)/denom, falling back to g on closed rounds.
 
@@ -171,6 +256,7 @@ def _merge_leaf_jnp(g, pods, w1, w2, denom, any_push):
     return jnp.where(any_push, merged, gf).astype(g.dtype)
 
 
+@scope(MERGE)
 def _merge_sliced(w_global, payloads, delta, fmt, w1, w2, denom, any_push,
                   n_pods):
     """Receiver-side merge over *gathered payload rows*, one pod at a time.
@@ -228,6 +314,7 @@ def _merge_sliced(w_global, payloads, delta, fmt, w1, w2, denom, any_push,
     return jax.tree.unflatten(treedef, out)
 
 
+@scope(MERGE)
 def _merge_recv(w_global, recv, w1, w2, denom, any_push, use_kernel,
                 mesh=None):
     """The reconstructed-tree merge (uncompressed or decode-fallback path).
@@ -245,6 +332,51 @@ def _merge_recv(w_global, recv, w1, w2, denom, any_push, use_kernel,
     return jax.tree.map(
         lambda g, p: _merge_leaf_jnp(g, p, w1, w2, denom, any_push),
         w_global, recv)
+
+
+def _merge_payloads(w_global, payloads, delta, fmt, w1, w2, denom, any_push,
+                    n_pods, use_kernel, mesh=None, pod_axis="pod"):
+    """Merge gathered encoded payloads into the global model; ``delta``
+    gives only the stacked leaf shapes and dtypes.
+
+    * fused kernels (the format has a ``fused_merge`` hook): the gathered
+      payloads flow through the merge, dequantized inside the kernel's
+      VMEM pass.  A leaf whose blocked axis is the pod axis itself
+      (stacked scalars) has no per-pod block layout, so it falls back to
+      the decoded form;
+    * other kernels: the stacked reconstruction, pinned pod-replicated so
+      GSPMD cannot re-shard the decode, through :func:`_merge_recv`;
+    * jnp: decode the gathered payloads row by row and merge locally
+      (:func:`_merge_sliced`).
+    """
+    if use_kernel and fmt.fused_merge is not None:
+        from repro.dist.wire import block_axis
+        g_leaves, treedef = jax.tree.flatten(w_global)
+        p_leaves = treedef.flatten_up_to(payloads)
+        d_leaves = treedef.flatten_up_to(delta)
+
+        def _fallback(g, p, dl):
+            r = fmt.decode(p, dl.shape, dl.dtype)
+            pods = pin_gathered(g[None] + r, mesh, axis=pod_axis,
+                                n_pods=n_pods)
+            return _merge_leaf_jnp(g, pods, w1, w2, denom, any_push)
+
+        with scope(MERGE):
+            merged = [
+                fmt.fused_merge(g, p, w2, denom, any_push)
+                if block_axis((n_pods,) + tuple(g.shape)) >= 1
+                else _fallback(g, p, dl)
+                for g, p, dl in zip(g_leaves, p_leaves, d_leaves)]
+        return jax.tree.unflatten(treedef, merged)
+    if use_kernel:
+        with scope(MERGE):
+            rec = decode_tree(payloads, delta, fmt)
+            rec = pin_gathered(rec, mesh, axis=pod_axis, n_pods=n_pods)
+            recv = jax.tree.map(lambda g, d: g[None] + d, w_global, rec)
+        return _merge_recv(w_global, recv, w1, w2, denom, any_push,
+                           use_kernel, mesh)
+    return _merge_sliced(w_global, payloads, delta, fmt, w1, w2, denom,
+                         any_push, n_pods)
 
 
 def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
@@ -309,92 +441,31 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
         gates = gates & live.astype(bool)
     n_pods = int(gates.shape[0])
     any_push = jnp.any(gates)
-    w1 = 1.0 / jnp.maximum(jnp.asarray(L, jnp.float32), _EPS)
-    w2 = jnp.where(gates,
-                   1.0 / jnp.maximum(losses.astype(jnp.float32), _EPS), 0.0)
-    denom = w1 + jnp.sum(w2)
-
-    # What the PS actually receives: gate-open pods ship (w_i - w_global),
-    # compressed, with their accumulated error folded in (error feedback).
-    # Closed pods transmit nothing — they are zero-masked out of every wire
-    # and merge term so a diverged (nonfinite) local replica cannot poison
-    # the global model through its 0-weight contribution (0 * nan = nan).
-    def _gate_zero(leaf):
-        return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
+    w1, w2, denom = _merge_weights(gates, losses, L)
 
     if compression != "none":
         fmt = get_format(compression, use_kernel=use_kernel, mesh=mesh,
                          axis=pod_axis)
-        fused = use_kernel and fmt.fused_merge is not None
-        delta = jax.tree.map(
-            lambda p, g: _gate_zero(p - g[None]), pod_params, w_global)
-        err_in = (None if error is None
-                  else jax.tree.map(_gate_zero, error))
-        # Sender-side: encode, and keep the residual local — error
-        # feedback is each pod's private bookkeeping of what its own wire
-        # dropped, so it never crosses the pod axis.  The decode-side
-        # reconstruction is only built when the residual consumes it.
-        payloads, _, residual = encode_tree(
-            delta, fmt, error=err_in, rng=rng, with_residual=track_error)
-        if not track_error:
-            new_error = None
-        elif error is None:
-            new_error = jax.tree.map(_gate_zero, residual)
-        else:
-            new_error = jax.tree.map(
-                lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
-                residual, error)
+        delta, payloads, new_error = _encode(
+            pod_params, w_global, gates, error, fmt, rng=rng,
+            track_error=track_error)
         # The ship: the encoded wire arrays are what cross the pod axis.
         payloads = gather_payloads(payloads, mesh, axis=pod_axis,
                                    n_pods=n_pods)
-        if fused:
-            # Gathered payloads flow through the merge: the fused kernel
-            # dequantizes (q, scales) inside its VMEM pass.  A leaf whose
-            # blocked axis is the pod axis itself (stacked scalars) has no
-            # per-pod block layout, so it falls back to the decoded form.
-            from repro.dist.wire import block_axis
-            g_leaves, treedef = jax.tree.flatten(w_global)
-            p_leaves = treedef.flatten_up_to(payloads)
-            d_leaves = treedef.flatten_up_to(delta)
-
-            def _fallback(g, p, dl):
-                r = fmt.decode(p, dl.shape, dl.dtype)
-                pods = pin_gathered(g[None] + r, mesh, axis=pod_axis,
-                                    n_pods=n_pods)
-                return _merge_leaf_jnp(g, pods, w1, w2, denom, any_push)
-
-            merged = [
-                fmt.fused_merge(g, p, w2, denom, any_push)
-                if block_axis((n_pods,) + tuple(g.shape)) >= 1
-                else _fallback(g, p, dl)
-                for g, p, dl in zip(g_leaves, p_leaves, d_leaves)]
-            new_global = jax.tree.unflatten(treedef, merged)
-        elif use_kernel:
-            # Kernel merge wants the stacked reconstruction; pin it
-            # pod-replicated so GSPMD cannot re-shard the decode.
-            rec = decode_tree(payloads, delta, fmt)
-            rec = pin_gathered(rec, mesh, axis=pod_axis, n_pods=n_pods)
-            recv = jax.tree.map(lambda g, d: g[None] + d, w_global, rec)
-            new_global = _merge_recv(w_global, recv, w1, w2, denom,
-                                     any_push, use_kernel, mesh)
-        else:
-            # Receiver-side: decode the *gathered* payloads row by row
-            # and merge locally (see _merge_sliced for why slicewise).
-            new_global = _merge_sliced(w_global, payloads, delta, fmt,
-                                       w1, w2, denom, any_push, n_pods)
+        new_global = _merge_payloads(w_global, payloads, delta, fmt, w1, w2,
+                                     denom, any_push, n_pods, use_kernel,
+                                     mesh, pod_axis)
     else:
         # Uncompressed wire: the gate-zeroed replicas themselves are the
         # payload; they cross the pod axis the same explicit way.
-        recv = jax.tree.map(_gate_zero, pod_params)
+        with scope(ENCODE):
+            recv = jax.tree.map(lambda p: _gate_zero(gates, p), pod_params)
         recv = gather_payloads(recv, mesh, axis=pod_axis, n_pods=n_pods)
         new_error = error if track_error else None
         new_global = _merge_recv(w_global, recv, w1, w2, denom,
                                  any_push, use_kernel, mesh)
 
-    # refresh: pushing pods restart from the merged global model
-    new_pods = jax.tree.map(
-        lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
-        pod_params, new_global)
+    new_pods = _refresh(pod_params, new_global, gates)
     return new_pods, new_global, new_error, any_push
 
 
@@ -443,18 +514,7 @@ def hermes_round(pod_params: Tree, gup_state: Tree, pod_losses: jnp.ndarray,
     if use_kernel is None:
         use_kernel = resolve_kernel_dispatch(
             getattr(cfg, "kernel_dispatch", "auto"))
-    gates, new_gup = jax.vmap(
-        lambda s, x: gup_gate_jax(s, x, cfg))(gup_state, pod_losses)
-    gates = gates.astype(bool)
-    if live is not None:
-        gates = gates & live.astype(bool)
-    # participation budget AFTER the gate+live mask and BEFORE any_push /
-    # wire / merge / refresh: a deferred pod behaves exactly like a closed
-    # one downstream (the per-pod GUP bookkeeping above already advanced
-    # on the RAW gate decision — deferral is a transport policy, not a
-    # gate override).  At participation_rate=1.0 this is `gates` itself.
-    gates = admit_gates(gates, pod_losses, cfg, rng=rng)
-    any_push = jnp.any(gates)
+    gates, new_gup, any_push = _gate(gup_state, pod_losses, cfg, live, rng)
     err_in = error if cfg.error_feedback else None
     # hermes_merge tracks a residual for every non-"none" format (lossless
     # ones just carry exact zeros), so the closed branch must mirror that
@@ -553,42 +613,21 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
       local steps and hand it to :func:`hermes_commit`; resizes must flush
       it first (``launch/elastic.py``).
     """
-    gates, new_gup = jax.vmap(
-        lambda s, x: gup_gate_jax(s, x, cfg))(gup_state, pod_losses)
-    gates = gates.astype(bool)
-    if live is not None:
-        gates = gates & live.astype(bool)
-    # participation budget (see hermes_round / admit_gates): the pending
-    # buffer carries the ADMITTED gates, so the matching commit merges
-    # and refreshes exactly the pods whose payload actually shipped.
-    gates = admit_gates(gates, pod_losses, cfg, rng=rng)
+    # the pending buffer carries the ADMITTED gates, so the matching commit
+    # merges and refreshes exactly the pods whose payload actually shipped
+    gates, new_gup, any_push = _gate(gup_state, pod_losses, cfg, live, rng)
     n_pods = int(gates.shape[0])
-    any_push = jnp.any(gates)
     compressed = cfg.compression != "none"
     track_error = cfg.error_feedback
     err_in = error if track_error else None
     fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
         getattr(cfg, "kernel_dispatch", "auto")), mesh=mesh, axis=pod_axis)
 
-    def _gate_zero(leaf):
-        return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
-
     if compressed:
         def _open(args):
             pods, wg, err = args
-            delta = jax.tree.map(
-                lambda p, g: _gate_zero(p - g[None]), pods, wg)
-            e_in = None if err is None else jax.tree.map(_gate_zero, err)
-            payloads, _, residual = encode_tree(
-                delta, fmt, error=e_in, rng=rng, with_residual=track_error)
-            if not track_error:
-                new_error = None
-            elif err is None:
-                new_error = jax.tree.map(_gate_zero, residual)
-            else:
-                new_error = jax.tree.map(
-                    lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
-                    residual, err)
+            _, payloads, new_error = _encode(
+                pods, wg, gates, err, fmt, rng=rng, track_error=track_error)
             shipped = gather_payloads(payloads, mesh, axis=pod_axis,
                                       n_pods=n_pods)
             return shipped, new_error
@@ -611,8 +650,9 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
         # error residual passes through unchanged (a lossless wire drops
         # nothing).
         def _open(pods):
-            recv = jax.tree.map(_gate_zero, pods)
-            payloads, _, _ = encode_tree(recv, fmt, with_residual=False)
+            with scope(ENCODE):
+                recv = jax.tree.map(lambda p: _gate_zero(gates, p), pods)
+                payloads, _, _ = encode_tree(recv, fmt, with_residual=False)
             return gather_payloads(payloads, mesh, axis=pod_axis,
                                    n_pods=n_pods)
 
@@ -672,72 +712,35 @@ def hermes_commit(pod_params: Tree, pending: Dict[str, Any], w_global: Tree,
     gates = pending["gates"].astype(bool)
     if live is not None:
         gates = gates & live.astype(bool)
-    losses = pending["losses"].astype(jnp.float32)
-    L = pending["L"]
     n_pods = int(gates.shape[0])
     any_push = jnp.any(gates)
-    w1 = 1.0 / jnp.maximum(jnp.asarray(L, jnp.float32), _EPS)
-    w2 = jnp.where(gates,
-                   1.0 / jnp.maximum(losses, _EPS), 0.0)
-    denom = w1 + jnp.sum(w2)
+    w1, w2, denom = _merge_weights(gates, pending["losses"], pending["L"])
     payload = pending["payload"]
-    compressed = cfg.compression != "none"
 
     def _open(args):
         pods, wg = args
-        if compressed:
+        # The merge machinery only reads shapes/dtypes from the stacked
+        # tree; the values stayed on the sender.  (A dead-at-commit pod's
+        # payload row was encoded while it was still finite, and its w2 is
+        # zero, so the row contributes an exact 0.)
+        stacked_t = jax.tree.map(
+            lambda g: jax.ShapeDtypeStruct((n_pods,) + tuple(g.shape),
+                                           g.dtype), wg)
+        if cfg.compression != "none":
             fmt = get_format(cfg.compression, use_kernel=use_kernel,
                              mesh=mesh, axis=pod_axis)
-            fused = use_kernel and fmt.fused_merge is not None
-            # The merge machinery only reads shapes/dtypes from the delta
-            # tree; the values stayed on the sender.  (A dead-at-commit
-            # pod's payload row was encoded while it was still finite, and
-            # its w2 is zero, so the row contributes an exact 0.)
-            delta_t = jax.tree.map(
-                lambda g: jax.ShapeDtypeStruct((n_pods,) + tuple(g.shape),
-                                               g.dtype), wg)
-            if fused:
-                from repro.dist.wire import block_axis
-                g_leaves, treedef = jax.tree.flatten(wg)
-                p_leaves = treedef.flatten_up_to(payload)
-                d_leaves = treedef.flatten_up_to(delta_t)
-
-                def _fallback(g, p, dl):
-                    r = fmt.decode(p, dl.shape, dl.dtype)
-                    stacked = pin_gathered(g[None] + r, mesh, axis=pod_axis,
-                                           n_pods=n_pods)
-                    return _merge_leaf_jnp(g, stacked, w1, w2, denom,
-                                           any_push)
-
-                merged = [
-                    fmt.fused_merge(g, p, w2, denom, any_push)
-                    if block_axis((n_pods,) + tuple(g.shape)) >= 1
-                    else _fallback(g, p, dl)
-                    for g, p, dl in zip(g_leaves, p_leaves, d_leaves)]
-                new_global = jax.tree.unflatten(treedef, merged)
-            elif use_kernel:
-                rec = decode_tree(payload, delta_t, fmt)
-                rec = pin_gathered(rec, mesh, axis=pod_axis, n_pods=n_pods)
-                recv = jax.tree.map(lambda g, d: g[None] + d, wg, rec)
-                new_global = _merge_recv(wg, recv, w1, w2, denom,
-                                         any_push, use_kernel, mesh)
-            else:
-                new_global = _merge_sliced(wg, payload, delta_t, fmt,
-                                           w1, w2, denom, any_push, n_pods)
+            new_global = _merge_payloads(wg, payload, stacked_t, fmt, w1, w2,
+                                         denom, any_push, n_pods, use_kernel,
+                                         mesh, pod_axis)
         else:
             # Uncompressed pending payload rows are the replicas themselves,
             # shipped in the lossless format's payload-dict structure (so
             # the buffer matches payload_buffer_spec); decoding is identity.
-            rep_t = jax.tree.map(
-                lambda g: jax.ShapeDtypeStruct((n_pods,) + tuple(g.shape),
-                                               g.dtype), wg)
-            recv = decode_tree(payload, rep_t, cfg.compression)
+            with scope(MERGE):
+                recv = decode_tree(payload, stacked_t, cfg.compression)
             new_global = _merge_recv(wg, recv, w1, w2, denom,
                                      any_push, use_kernel, mesh)
-        new_pods = jax.tree.map(
-            lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
-            pods, new_global)
-        return new_pods, new_global
+        return _refresh(pods, new_global, gates), new_global
 
     def _closed(args):
         return args
@@ -811,6 +814,7 @@ def _cluster_index(n_pods: int, n_clusters: int,
     return np.repeat(np.arange(len(sizes)), sizes)
 
 
+@scope(MERGE)
 def _cluster_partials(w_global: Tree, payloads: Tree, delta: Tree, fmt,
                       w2: jnp.ndarray, n_pods: int, n_clusters: int,
                       cluster_sizes: Optional[Sequence[int]] = None) -> Tree:
@@ -897,6 +901,7 @@ def _cluster_partials(w_global: Tree, payloads: Tree, delta: Tree, fmt,
     return jax.tree.unflatten(treedef, out)
 
 
+@scope(MERGE)
 def _merge_cluster(w_global: Tree, cpayloads: Tree, stacked_t: Tree, fmt,
                    denom, any_push, n_clusters: int) -> Tree:
     """Fold the gathered per-cluster partials into the global model:
@@ -970,6 +975,30 @@ def _mask_cluster_rows(cpayloads: Tree, keep_c: jnp.ndarray,
     return jax.tree.map(_mask, cpayloads)
 
 
+@scope(ENCODE)
+def _encode_partials(partials: Tree, w_global: Tree, fmt, rng, mesh,
+                     cluster_axis: str, n_clusters: int) -> Tree:
+    """The slow-tier re-encode of the stacked ``(C,)+leaf`` partials, in
+    the leaf dtype and cluster-sharded (a fully closed cluster's partial is
+    exact zeros, which every format encodes/decodes to exact zeros).  No
+    error feedback at this tier (DESIGN.md §10).
+
+    The first barrier keeps the accumulate's arithmetic independent of
+    what consumes the re-encoded payload, and the second the encode's: in
+    the dispatch/commit split the payload is a cond output (a natural
+    fusion boundary), so both pin the sync round to bit-identical cluster
+    payloads.
+    """
+    partials = jax.tree.map(
+        lambda a, g: a.astype(g.dtype), partials, w_global)
+    partials = jax.lax.optimization_barrier(partials)
+    partials = pin_tier(partials, mesh, lead=cluster_axis, n_rows=n_clusters)
+    crng = None if rng is None else jax.random.fold_in(rng, 0x5C1)
+    cpayloads, _, _ = encode_tree(partials, fmt, rng=crng,
+                                  with_residual=False)
+    return jax.lax.optimization_barrier(cpayloads)
+
+
 def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
                          losses: jnp.ndarray, w_global: Tree, L: jnp.ndarray,
                          *, n_clusters: int,
@@ -1018,59 +1047,23 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
             "flat round until the cluster grid rebalances")
     _cluster_index(n_pods, C, cluster_sizes)  # validates the split
     any_push = jnp.any(gates)
-    w1 = 1.0 / jnp.maximum(jnp.asarray(L, jnp.float32), _EPS)
-    w2 = jnp.where(gates,
-                   1.0 / jnp.maximum(losses.astype(jnp.float32), _EPS), 0.0)
-    denom = w1 + jnp.sum(w2)
-
-    def _gate_zero(leaf):
-        return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
+    _, w2, denom = _merge_weights(gates, losses, L)
 
     fmt = get_format(compression, use_kernel=use_kernel and mesh is None)
-    delta = jax.tree.map(
-        lambda p, g: _gate_zero(p - g[None]), pod_params, w_global)
-    if compression != "none":
-        err_in = None if error is None else jax.tree.map(_gate_zero, error)
-        payloads, _, residual = encode_tree(
-            delta, fmt, error=err_in, rng=rng, with_residual=track_error)
-        if not track_error:
-            new_error = None
-        elif error is None:
-            new_error = jax.tree.map(_gate_zero, residual)
-        else:
-            new_error = jax.tree.map(
-                lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
-                residual, error)
-    else:
-        # Lossless wire: unlike the flat merge (which ships gate-zeroed
-        # replicas), the two-tier path ships the DELTA uniformly for all
-        # formats — the partial-sum identity needs r_i, not w_i — and a
-        # lossless wire drops nothing, so the residual passes through.
-        payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
-        new_error = error if track_error else None
+    # Lossless wire: unlike the flat merge (which ships gate-zeroed
+    # replicas), the two-tier path ships the DELTA uniformly for all
+    # formats — the partial-sum identity needs r_i, not w_i.
+    delta, payloads, new_error = _encode(
+        pod_params, w_global, gates, error, fmt, rng=rng,
+        track_error=track_error, lossy=compression != "none")
 
     # Fast tier: every cluster gathers its own members' payload rows.
     payloads = gather_payloads_tiered(payloads, mesh, axis=pod_axis,
                                       keep=cluster_axis, n_rows=n_pods)
     partials = _cluster_partials(w_global, payloads, delta, fmt, w2,
                                  n_pods, C, cluster_sizes)
-    # Stacked (C,)+leaf partials in the leaf dtype, cluster-sharded, ready
-    # for the slow-tier re-encode (a fully closed cluster's partial is
-    # exact zeros, which every format encodes/decodes to exact zeros).
-    # The barrier keeps the accumulate's arithmetic independent of what
-    # consumes the re-encoded payload, so the sync round and the
-    # dispatch/commit split produce bit-identical cluster payloads.
-    partials = jax.tree.map(
-        lambda a, g: a.astype(g.dtype), partials, w_global)
-    partials = jax.lax.optimization_barrier(partials)
-    partials = pin_tier(partials, mesh, lead=cluster_axis, n_rows=C)
-    crng = None if rng is None else jax.random.fold_in(rng, 0x5C1)
-    cpayloads, _, _ = encode_tree(partials, fmt, rng=crng,
-                                  with_residual=False)
-    # Barrier the wire bits too: in the dispatch/commit split the payload
-    # is a cond output (a natural fusion boundary); pinning it here keeps
-    # the sync round's encode arithmetic identical to dispatch's.
-    cpayloads = jax.lax.optimization_barrier(cpayloads)
+    cpayloads = _encode_partials(partials, w_global, fmt, rng, mesh,
+                                 cluster_axis, C)
     # Slow tier: ONE payload per cluster crosses the cluster axis.
     cpayloads = gather_payloads(cpayloads, mesh, axis=cluster_axis,
                                 n_pods=C)
@@ -1079,9 +1072,7 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
         w_global)
     new_global = _merge_cluster(w_global, cpayloads, stacked_t, fmt,
                                 denom, any_push, C)
-    new_pods = jax.tree.map(
-        lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
-        pod_params, new_global)
+    new_pods = _refresh(pod_params, new_global, gates)
     return new_pods, new_global, new_error, any_push
 
 
@@ -1116,15 +1107,7 @@ def hermes_cluster_round(pod_params: Tree, gup_state: Tree,
                             cfg, live=live, error=error,
                             use_kernel=use_kernel, rng=rng, mesh=mesh,
                             pod_axis=pod_axis)
-    gates, new_gup = jax.vmap(
-        lambda s, x: gup_gate_jax(s, x, cfg))(gup_state, pod_losses)
-    gates = gates.astype(bool)
-    if live is not None:
-        gates = gates & live.astype(bool)
-    # same admission point as the flat round (the C<=1 delegation above
-    # already applied it through hermes_round)
-    gates = admit_gates(gates, pod_losses, cfg, rng=rng)
-    any_push = jnp.any(gates)
+    gates, new_gup, any_push = _gate(gup_state, pod_losses, cfg, live, rng)
     err_in = error if cfg.error_feedback else None
     compressed = cfg.compression != "none"
 
@@ -1189,66 +1172,32 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
         return hermes_dispatch(pod_params, gup_state, pod_losses, w_global,
                                L, cfg, live=live, error=error, rng=rng,
                                mesh=mesh, pod_axis=pod_axis)
-    gates, new_gup = jax.vmap(
-        lambda s, x: gup_gate_jax(s, x, cfg))(gup_state, pod_losses)
-    gates = gates.astype(bool)
-    if live is not None:
-        gates = gates & live.astype(bool)
-    # same admission point as the flat dispatch (the C<=1 delegation
-    # above already applied it through hermes_dispatch)
-    gates = admit_gates(gates, pod_losses, cfg, rng=rng)
+    gates, new_gup, any_push = _gate(gup_state, pod_losses, cfg, live, rng)
     n_pods = int(gates.shape[0])
     if cluster_sizes is not None:
         assert mesh is None, (
             "uneven cluster_sizes run unplaced; a placed run uses the "
             "flat dispatch until the cluster grid rebalances")
     _cluster_index(n_pods, C, cluster_sizes)
-    any_push = jnp.any(gates)
     compressed = cfg.compression != "none"
     track_error = cfg.error_feedback
     err_in = error if track_error else None
-    w2 = jnp.where(gates,
-                   1.0 / jnp.maximum(pod_losses.astype(jnp.float32), _EPS),
-                   0.0)
+    _, w2, _ = _merge_weights(gates, pod_losses, L)
     # wire kernels only unplaced, as in hermes_cluster_merge
     fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
         getattr(cfg, "kernel_dispatch", "auto")) and mesh is None)
 
-    def _gate_zero(leaf):
-        return jnp.where(_pod_mask(gates, leaf), leaf, jnp.zeros_like(leaf))
-
     def _open(args):
         pods, wg, err = args
-        delta = jax.tree.map(
-            lambda p, g: _gate_zero(p - g[None]), pods, wg)
-        if compressed:
-            e_in = None if err is None else jax.tree.map(_gate_zero, err)
-            payloads, _, residual = encode_tree(
-                delta, fmt, error=e_in, rng=rng, with_residual=track_error)
-            if not track_error:
-                new_error = None
-            elif err is None:
-                new_error = jax.tree.map(_gate_zero, residual)
-            else:
-                new_error = jax.tree.map(
-                    lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
-                    residual, err)
-        else:
-            payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
-            new_error = err
+        delta, payloads, new_error = _encode(
+            pods, wg, gates, err, fmt, rng=rng, track_error=track_error,
+            lossy=compressed)
         shipped = gather_payloads_tiered(payloads, mesh, axis=pod_axis,
                                          keep=cluster_axis, n_rows=n_pods)
         partials = _cluster_partials(wg, shipped, delta, fmt, w2,
                                      n_pods, C, cluster_sizes)
-        # Same barrier as the sync merge: pins the partials' arithmetic
-        # against downstream fusion so both halves ship identical bits.
-        partials = jax.tree.map(lambda a, g: a.astype(g.dtype), partials, wg)
-        partials = jax.lax.optimization_barrier(partials)
-        partials = pin_tier(partials, mesh, lead=cluster_axis, n_rows=C)
-        crng = None if rng is None else jax.random.fold_in(rng, 0x5C1)
-        cpayloads, _, _ = encode_tree(partials, fmt, rng=crng,
-                                      with_residual=False)
-        cpayloads = jax.lax.optimization_barrier(cpayloads)
+        cpayloads = _encode_partials(partials, wg, fmt, rng, mesh,
+                                     cluster_axis, C)
         cpayloads = gather_payloads(cpayloads, mesh, axis=cluster_axis,
                                     n_pods=C)
         return cpayloads, new_error
@@ -1324,13 +1273,9 @@ def hermes_cluster_commit(pod_params: Tree, pending: Dict[str, Any],
     keep_c = dropped == 0
     keep_pod = keep_c[cidx]
     gates = gates_d & lv & keep_pod
-    losses = pending["losses"].astype(jnp.float32)
-    L = pending["L"]
     any_push = jnp.any(gates)
-    w1 = 1.0 / jnp.maximum(jnp.asarray(L, jnp.float32), _EPS)
-    w2 = jnp.where(gates_d & keep_pod,
-                   1.0 / jnp.maximum(losses, _EPS), 0.0)
-    denom = w1 + jnp.sum(w2)
+    _, _, denom = _merge_weights(gates_d & keep_pod, pending["losses"],
+                                 pending["L"])
     payload = _mask_cluster_rows(pending["cluster_payload"], keep_c, C)
     fmt = get_format(cfg.compression, use_kernel=resolve_kernel_dispatch(
         getattr(cfg, "kernel_dispatch", "auto")) and mesh is None)
@@ -1342,10 +1287,7 @@ def hermes_cluster_commit(pod_params: Tree, pending: Dict[str, Any],
         pods, wg = args
         new_global = _merge_cluster(wg, payload, stacked_t, fmt, denom,
                                     any_push, C)
-        new_pods = jax.tree.map(
-            lambda p, g: jnp.where(_pod_mask(gates, p), g[None], p),
-            pods, new_global)
-        return new_pods, new_global
+        return _refresh(pods, new_global, gates), new_global
 
     def _closed(args):
         return args

@@ -47,6 +47,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.utils.tracing import GATHER, scope
+
 Payload = Dict[str, jnp.ndarray]
 
 BLOCK = 256  # absmax block along the last axis; kernels/quantize.py agrees
@@ -541,6 +543,7 @@ def payload_buffer_spec(tree: Any, mode: str, n_pods: int) -> Any:
 # The cross-pod ship: explicit payload gather
 # ---------------------------------------------------------------------------
 
+@scope(GATHER)
 def gather_payloads(payloads: Any, mesh, *, axis: str = "pod",
                     n_pods: Optional[int] = None) -> Any:
     """Ship an encoded payload tree across the ``axis`` mesh axis.
@@ -640,6 +643,7 @@ def pin_gathered(tree: Any, mesh, *, axis: str = "pod",
     return jax.tree.map(_pin, tree)
 
 
+@scope(GATHER)
 def gather_payloads_tiered(payloads: Any, mesh, *, axis: str = "pod",
                            keep: str = "cluster",
                            n_rows: Optional[int] = None) -> Any:

@@ -56,6 +56,8 @@ from repro.dist.hermes_sync import (
 from repro.launch.mesh import make_pod_mesh
 from repro.models import init_lm, lm_loss
 from repro.optim import make_optimizer
+from repro.utils import tracing
+from repro.utils.tracing import span
 
 Tree = Any
 
@@ -228,6 +230,7 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                  opt_cfg: OptimizerConfig, ckpt_dir: Optional[str] = None,
                  restore: bool = False, log_every: int = 20,
                  seed: int = 0) -> Dict:
+    tracing.install_gc_span()
     rng = np.random.default_rng(seed)
     tokens = make_lm_dataset(batch * seq * 40 + 1, cfg.vocab_size, seed=seed)
     optimizer = make_optimizer(opt_cfg)
@@ -260,19 +263,29 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     log_times = []  # (step, wall s since loop start), taken after a sync
     t0 = time.time()
     for i in range(start_step, steps):
-        state, loss = step_fn(state, next(batches))
-        losses.append(float(loss))
-        if (i + 1) % log_every == 0:
-            jax.block_until_ready(state)
-            log_times.append((i + 1, time.time() - t0))
-            print(f"step {i+1:5d} loss {np.mean(losses[-log_every:]):.4f} "
-                  f"({(i + 1 - start_step) / (time.time() - t0):.2f} it/s)",
-                  flush=True)
+        log = (i + 1) % log_every == 0
+        with span(tracing.BATCH, step=i):
+            batch_i = next(batches)
+        with span(tracing.STEP, step=i):
+            state, loss = step_fn(state, batch_i)
+        with span(tracing.FETCH, step=i):
+            losses.append(float(loss))
+            if log:
+                jax.block_until_ready(state)
+        if log:
+            with span(tracing.LOG, step=i):
+                log_times.append((i + 1, time.time() - t0))
+                print(f"step {i+1:5d} loss "
+                      f"{np.mean(losses[-log_every:]):.4f} "
+                      f"({(i + 1 - start_step) / (time.time() - t0):.2f} "
+                      f"it/s)", flush=True)
         if ck and (i + 1) % 100 == 0:
-            ck.save(state, i + 1)
+            with span(tracing.CKPT, step=i):
+                ck.save(state, i + 1)
     if ck:
-        ck.save(state, steps)
-        ck.wait()
+        with span(tracing.CKPT, step=steps):
+            ck.save(state, steps)
+            ck.wait()
     # a restore at/after `steps` runs zero iterations; report nan, don't crash
     return {"final_loss": float(np.mean(losses[-10:])) if losses
             else float("nan"),
@@ -320,6 +333,7 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     ``gates`` holds each round's per-pod gate row (0/1), and ``history``
     each round's ``(step, mean pod loss, open gates)``.
     """
+    tracing.install_gc_span()
     rng = np.random.default_rng(seed)
     tokens = make_lm_dataset(batch * seq * 40 * pods + batch * seq + 2,
                              cfg.vocab_size, seed=seed)
@@ -377,11 +391,13 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     else:
         round_jit = make_round_jit(hcfg, mesh)
 
-    def _commit_pending(pod_params, w_global, L_global, pending, counters):
+    def _commit_pending(pod_params, w_global, L_global, pending, counters,
+                        step):
         merges_dev, committed_dev = counters
         cm = commit_jit(pod_params, pending, w_global)
         pod_params, w_global = cm["pod_params"], cm["w_global"]
-        L_global = eval_if_push(cm["any_push"], w_global, L_global)
+        with span(tracing.EVAL, step=step):
+            L_global = eval_if_push(cm["any_push"], w_global, L_global)
         bump = cm["any_push"].astype(jnp.int32)
         return pod_params, w_global, L_global, (merges_dev + bump,
                                                 committed_dev + bump)
@@ -395,53 +411,66 @@ def train_hermes(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     t0 = time.time()
     history_dev = []               # (step, device mean loss, device gates)
     for i in range(steps):
-        stacked = place({k: jnp.stack([next(b)[k] for b in batch_iters])
-                         for k in ("tokens", "targets")}, pod_sh)
-        pod_params, pod_opt, losses = pod_step(pod_params, pod_opt, stacked)
+        with span(tracing.BATCH, step=i):
+            stacked = place({k: jnp.stack([next(b)[k] for b in batch_iters])
+                             for k in ("tokens", "targets")}, pod_sh)
+        with span(tracing.STEP, step=i):
+            pod_params, pod_opt, losses = pod_step(pod_params, pod_opt,
+                                                   stacked)
         if (i + 1) % hcfg.lam == 0 or i == 0:
             rounds += 1
-            pod_losses = pod_eval(pod_params)
-            rng_i = jax.random.fold_in(jax.random.PRNGKey(seed), i)
-            if async_rounds:
-                # commit round k-1's in-flight payload first (its gather
-                # overlapped the lam steps just taken), then dispatch
-                # round k against the freshly merged global and return to
-                # compute without waiting on the new gather
-                if pending is not None:
-                    (pod_params, w_global, L_global,
-                     (merges_dev, committed_dev)) = _commit_pending(
-                        pod_params, w_global, L_global, pending,
-                        (merges_dev, committed_dev))
-                dp = dispatch_jit(pod_params, gup, pod_losses, w_global,
-                                  L_global, error, rng_i)
-                gup, error, pending = dp["gup"], dp["error"], dp["pending"]
-                dispatched_dev = (dispatched_dev
-                                  + dp["any_push"].astype(jnp.int32))
-                history_dev.append((i + 1, jnp.mean(pod_losses),
-                                    dp["gates"]))
-            else:
-                out = round_jit(pod_params, gup, pod_losses, w_global,
-                                L_global, error, rng_i)
-                pod_params, w_global = out["pod_params"], out["w_global"]
-                gup, error = out["gup"], out["error"]
-                L_global = eval_if_push(out["any_push"], w_global, L_global)
-                merges_dev = merges_dev + out["any_push"].astype(jnp.int32)
-                history_dev.append((i + 1, jnp.mean(pod_losses),
-                                    out["gates"]))
+            with span(tracing.EVAL, step=i):
+                pod_losses = pod_eval(pod_params)
+            with span(tracing.ROUND, step=i):
+                rng_i = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+                if async_rounds:
+                    # commit round k-1's in-flight payload first (its
+                    # gather overlapped the lam steps just taken), then
+                    # dispatch round k against the freshly merged global
+                    # and return to compute without waiting on the new
+                    # gather
+                    if pending is not None:
+                        (pod_params, w_global, L_global,
+                         (merges_dev, committed_dev)) = _commit_pending(
+                            pod_params, w_global, L_global, pending,
+                            (merges_dev, committed_dev), i)
+                    dp = dispatch_jit(pod_params, gup, pod_losses, w_global,
+                                      L_global, error, rng_i)
+                    gup, error, pending = (dp["gup"], dp["error"],
+                                           dp["pending"])
+                    dispatched_dev = (dispatched_dev
+                                      + dp["any_push"].astype(jnp.int32))
+                    history_dev.append((i + 1, jnp.mean(pod_losses),
+                                        dp["gates"]))
+                else:
+                    out = round_jit(pod_params, gup, pod_losses, w_global,
+                                    L_global, error, rng_i)
+                    pod_params, w_global = out["pod_params"], out["w_global"]
+                    gup, error = out["gup"], out["error"]
+                    with span(tracing.EVAL, step=i):
+                        L_global = eval_if_push(out["any_push"], w_global,
+                                                L_global)
+                    merges_dev = (merges_dev
+                                  + out["any_push"].astype(jnp.int32))
+                    history_dev.append((i + 1, jnp.mean(pod_losses),
+                                        out["gates"]))
         if (i + 1) % log_every == 0:
-            pod_l, gl_l, m = _host_fetch((jnp.mean(losses), L_global,
-                                          merges_dev))
-            log_times.append((i + 1, time.time() - t0))
-            print(f"step {i+1:5d} pod-loss {float(pod_l):.4f} "
-                  f"global-L {float(gl_l):.4f} merges={int(m)}/{rounds}",
-                  flush=True)
+            with span(tracing.FETCH, step=i):
+                pod_l, gl_l, m = _host_fetch((jnp.mean(losses), L_global,
+                                              merges_dev))
+            with span(tracing.LOG, step=i):
+                log_times.append((i + 1, time.time() - t0))
+                print(f"step {i+1:5d} pod-loss {float(pod_l):.4f} "
+                      f"global-L {float(gl_l):.4f} merges={int(m)}/{rounds}",
+                      flush=True)
     # drain: the last dispatched payload has no following boundary, so
     # flush it here — every open round merges exactly once
     if pending is not None:
-        (pod_params, w_global, L_global,
-         (merges_dev, committed_dev)) = _commit_pending(
-            pod_params, w_global, L_global, pending,
-            (merges_dev, committed_dev))
+        with span(tracing.ROUND, step=steps):
+            (pod_params, w_global, L_global,
+             (merges_dev, committed_dev)) = _commit_pending(
+                pod_params, w_global, L_global, pending,
+                (merges_dev, committed_dev), steps)
         pending = None
     # one bulk transfer: stack the per-round scalars on device first so
     # the final fetch is two arrays, not thousands of tiny copies

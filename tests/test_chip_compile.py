@@ -89,9 +89,10 @@ def test_wire_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_placed_round_keeps_kernels_per_device(topo, monkeypatch):
+@pytest.fixture(scope="module")
+def placed_round_text(topo):
     """The int4 round on a (4, 1, 1) pod mesh of described chips, kernels
-    forced on: it compiles, and its kernels are real TPU custom calls."""
+    forced on, compiled: its HLO text."""
     from repro.config import HermesConfig
     from repro.dist.hermes_sync import hermes_pod_state
     from repro.kernels import ops
@@ -101,29 +102,44 @@ def test_placed_round_keeps_kernels_per_device(topo, monkeypatch):
     # CPU here); steer them to the chip's lowering for this compile, and
     # drop jit caches that may hold interpret-mode traces of the same
     # shapes from other tests
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    jax.clear_caches()
-    try:
-        pods = 4
-        mesh = Mesh(np.asarray(topo.devices[:pods], dtype=object)
-                    .reshape(pods, 1, 1), ("pod", "data", "model"))
-        hcfg = HermesConfig(alpha=-0.5, lam=1, eta=1.0, compression="int4",
-                            kernel_dispatch="on")
-        pod_sh, rep_sh = pod_shardings(mesh)
-        params = {"w": jax.ShapeDtypeStruct((256, 1024), jnp.float32),
-                  "b": jax.ShapeDtypeStruct((1024,), jnp.float32)}
-
-        def sds(tree, sharding, lead=()):
-            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-                lead + a.shape, a.dtype, sharding=sharding), tree)
-
-        gup = jax.eval_shape(lambda: hermes_pod_state(hcfg, pods))
-        args = (sds(params, pod_sh, (pods,)), sds(gup, pod_sh),
-                jax.ShapeDtypeStruct((pods,), jnp.float32),
-                sds(params, rep_sh), jax.ShapeDtypeStruct((), jnp.float32),
-                sds(params, pod_sh, (pods,)),
-                jax.ShapeDtypeStruct((2,), jnp.uint32))
-        text = make_round_jit(hcfg, mesh).lower(*args).compile().as_text()
-    finally:
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
         jax.clear_caches()
-    assert text.count("tpu_custom_call") > 0
+        try:
+            pods = 4
+            mesh = Mesh(np.asarray(topo.devices[:pods], dtype=object)
+                        .reshape(pods, 1, 1), ("pod", "data", "model"))
+            hcfg = HermesConfig(alpha=-0.5, lam=1, eta=1.0,
+                                compression="int4", kernel_dispatch="on")
+            pod_sh, rep_sh = pod_shardings(mesh)
+            params = {"w": jax.ShapeDtypeStruct((256, 1024), jnp.float32),
+                      "b": jax.ShapeDtypeStruct((1024,), jnp.float32)}
+
+            def sds(tree, sharding, lead=()):
+                return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                    lead + a.shape, a.dtype, sharding=sharding), tree)
+
+            gup = jax.eval_shape(lambda: hermes_pod_state(hcfg, pods))
+            args = (sds(params, pod_sh, (pods,)), sds(gup, pod_sh),
+                    jax.ShapeDtypeStruct((pods,), jnp.float32),
+                    sds(params, rep_sh),
+                    jax.ShapeDtypeStruct((), jnp.float32),
+                    sds(params, pod_sh, (pods,)),
+                    jax.ShapeDtypeStruct((2,), jnp.uint32))
+            yield make_round_jit(hcfg, mesh).lower(*args).compile().as_text()
+        finally:
+            jax.clear_caches()
+
+
+def test_placed_round_keeps_kernels_per_device(placed_round_text):
+    """The placed round compiles, and its kernels are real TPU custom
+    calls."""
+    assert placed_round_text.count("tpu_custom_call") > 0
+
+
+def test_placed_round_carries_every_phase_scope(placed_round_text):
+    """The chip's compiler keeps the round's phase scopes in the op
+    metadata, the payload gather's among them."""
+    from repro.utils import tracing
+    for s in tracing.SCOPES:
+        assert f"/{s}/" in placed_round_text, s
