@@ -23,9 +23,10 @@ lint:
 	fi
 
 # Static round-invariant gate (DESIGN.md §9): repro.launch.analyze lowers
-# every entry point — hermes_round (open + closed), the async
-# dispatch/commit halves, the elastic shrink/grow rounds, the per-pod
-# train step, and the wire-path Pallas kernels — and runs the
+# every entry point — hermes_round (open + closed) and make_round_jit's
+# executable, the async dispatch/commit halves, the elastic shrink/grow
+# rounds, the per-pod train step, and the wire-path Pallas kernels — and
+# runs the
 # repro.analysis rules over the optimized HLO / jaxprs: collective
 # placement vs the wire registry, donation/aliasing, retrace/host-sync
 # guards, and the Pallas tile lint.  --self-test additionally proves each

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -272,9 +272,9 @@ def _conv_flops(result_type: str, operand_types: List[str], attrs: str) -> float
     return 2.0 * out_n * kn
 
 
-def parse_hlo_cost(hlo_text: str, entry: Optional[str] = None) -> HloCost:
-    """Compute the per-device cost of the ENTRY computation."""
-    # --- split into computations -----------------------------------------
+def split_computations(hlo_text: str
+                       ) -> Tuple[Dict[str, List[str]], Optional[str]]:
+    """``({computation name: its instruction lines}, ENTRY's name)``."""
     computations: Dict[str, List[str]] = {}
     entry_name = None
     cur: Optional[str] = None
@@ -292,7 +292,12 @@ def parse_hlo_cost(hlo_text: str, entry: Optional[str] = None) -> HloCost:
                 cur = None
             else:
                 computations[cur].append(stripped)
+    return computations, entry_name
 
+
+def parse_hlo_cost(hlo_text: str, entry: Optional[str] = None) -> HloCost:
+    """Compute the per-device cost of the ENTRY computation."""
+    computations, entry_name = split_computations(hlo_text)
     if entry is not None:
         entry_name = entry
     if entry_name is None:

@@ -183,34 +183,45 @@ def _merge_weights(gates: jnp.ndarray, losses: jnp.ndarray, L):
 def _encode(pods: Tree, w_global: Tree, gates: jnp.ndarray,
             error: Optional[Tree], fmt, *, rng=None, track_error: bool = True,
             lossy: bool = True) -> Tuple[Tree, Tree, Optional[Tree]]:
-    """Sender side of every round family: ``(delta, payloads, new_error)``.
+    """Sender side of every round family: ``(delta, payloads, residual)``.
 
     Gate-open pods ship ``w_i - w_global``, encoded with their accumulated
-    error folded in (error feedback); closed pods' rows are zeros.  The
-    residual stays local — each pod's private bookkeeping of what its own
-    wire dropped — and a closed pod keeps its pending error.  A lossless
-    wire (``lossy=False``) drops nothing, so the residual passes through.
+    error folded in (error feedback); closed pods' rows are zeros.
+    ``residual`` is what this round's wire dropped, row by row (zero rows
+    for closed pods), or None when no residual is tracked or the wire is
+    lossless (``lossy=False``); :func:`_keep_error` turns it into the error
+    each pod carries into the next round.
     """
     with scope(ENCODE):
         delta = jax.tree.map(
             lambda p, g: _gate_zero(gates, p - g[None]), pods, w_global)
         if not lossy:
             payloads, _, _ = encode_tree(delta, fmt, with_residual=False)
-            return delta, payloads, error if track_error else None
+            return delta, payloads, None
         err_in = (None if error is None
                   else jax.tree.map(lambda e: _gate_zero(gates, e), error))
         payloads, _, residual = encode_tree(
             delta, fmt, error=err_in, rng=rng, with_residual=track_error)
-        if not track_error:
-            new_error = None
-        elif error is None:
-            new_error = jax.tree.map(lambda r: _gate_zero(gates, r),
-                                     residual)
-        else:
-            new_error = jax.tree.map(
-                lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
-                residual, error)
-        return delta, payloads, new_error
+        return delta, payloads, residual
+
+
+def _keep_error(gates: jnp.ndarray, residual: Optional[Tree],
+                error: Optional[Tree], track_error: bool) -> Optional[Tree]:
+    """The error-feedback state after a round: an open pod takes this
+    round's ``residual``, a closed pod keeps its pending ``error`` (zero
+    before the first round).  The residual stays local — each pod's private
+    bookkeeping of what its own wire dropped.  With no residual (a lossless
+    or uncompressed wire drops nothing) the error passes through."""
+    if not track_error:
+        return None
+    if residual is None:
+        return error
+    with scope(ENCODE):
+        if error is None:
+            return jax.tree.map(lambda r: _gate_zero(gates, r), residual)
+        return jax.tree.map(
+            lambda r, e: jnp.where(_pod_mask(gates, r), r, e),
+            residual, error)
 
 
 def _refresh(pods: Tree, new_global: Tree, gates: jnp.ndarray) -> Tree:
@@ -439,34 +450,47 @@ def hermes_merge(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
     gates = gates.astype(bool)
     if live is not None:
         gates = gates & live.astype(bool)
+    new_global, residual = _push(
+        pod_params, gates, losses, w_global, L, compression=compression,
+        error=error, use_kernel=use_kernel, rng=rng, track_error=track_error,
+        mesh=mesh, pod_axis=pod_axis)
+    new_error = _keep_error(gates, residual, error, track_error)
+    new_pods = _refresh(pod_params, new_global, gates)
+    return new_pods, new_global, new_error, jnp.any(gates)
+
+
+def _push(pod_params: Tree, gates: jnp.ndarray, losses: jnp.ndarray,
+          w_global: Tree, L: jnp.ndarray, *, compression: str,
+          error: Optional[Tree], use_kernel: bool, rng, track_error: bool,
+          mesh, pod_axis: str) -> Tuple[Tree, Optional[Tree]]:
+    """What an open round computes and a closed one skips:
+    ``(new_global, residual)`` — encode, ship, and merge the open pods'
+    pushes, with :func:`_encode`'s raw residual (None on an uncompressed
+    wire or with no error feedback).  :func:`hermes_merge` and :func:`hermes_round` apply
+    :func:`_keep_error` and :func:`_refresh` to it."""
     n_pods = int(gates.shape[0])
     any_push = jnp.any(gates)
     w1, w2, denom = _merge_weights(gates, losses, L)
 
-    if compression != "none":
-        fmt = get_format(compression, use_kernel=use_kernel, mesh=mesh,
-                         axis=pod_axis)
-        delta, payloads, new_error = _encode(
-            pod_params, w_global, gates, error, fmt, rng=rng,
-            track_error=track_error)
-        # The ship: the encoded wire arrays are what cross the pod axis.
-        payloads = gather_payloads(payloads, mesh, axis=pod_axis,
-                                   n_pods=n_pods)
-        new_global = _merge_payloads(w_global, payloads, delta, fmt, w1, w2,
-                                     denom, any_push, n_pods, use_kernel,
-                                     mesh, pod_axis)
-    else:
+    if compression == "none":
         # Uncompressed wire: the gate-zeroed replicas themselves are the
         # payload; they cross the pod axis the same explicit way.
         with scope(ENCODE):
             recv = jax.tree.map(lambda p: _gate_zero(gates, p), pod_params)
         recv = gather_payloads(recv, mesh, axis=pod_axis, n_pods=n_pods)
-        new_error = error if track_error else None
-        new_global = _merge_recv(w_global, recv, w1, w2, denom,
-                                 any_push, use_kernel, mesh)
-
-    new_pods = _refresh(pod_params, new_global, gates)
-    return new_pods, new_global, new_error, any_push
+        return _merge_recv(w_global, recv, w1, w2, denom, any_push,
+                           use_kernel, mesh), None
+    fmt = get_format(compression, use_kernel=use_kernel, mesh=mesh,
+                     axis=pod_axis)
+    delta, payloads, residual = _encode(
+        pod_params, w_global, gates, error, fmt, rng=rng,
+        track_error=track_error)
+    # The ship: the encoded wire arrays are what cross the pod axis.
+    payloads = gather_payloads(payloads, mesh, axis=pod_axis, n_pods=n_pods)
+    new_global = _merge_payloads(w_global, payloads, delta, fmt, w1, w2,
+                                 denom, any_push, n_pods, use_kernel, mesh,
+                                 pod_axis)
+    return new_global, residual
 
 
 def hermes_round(pod_params: Tree, gup_state: Tree, pod_losses: jnp.ndarray,
@@ -493,10 +517,17 @@ def hermes_round(pod_params: Tree, gup_state: Tree, pod_losses: jnp.ndarray,
     ``w_global`` (grow — the newcomer's empty loss queue keeps its gate
     shut while it warms up, so incumbents never see the join).
 
-    The merge is wrapped in ``jax.lax.cond`` on ``any_push``: the gate
-    reduction is one scalar, and a fully closed round takes the identity
-    branch — it never pays the merge collective's latency, and its output
-    is bit-identical to the inputs (the ROADMAP "Gate/merge overlap" item).
+    Only what a closed round skips sits under ``jax.lax.cond`` on
+    ``any_push``: encode, ship and merge (:func:`_push`), whose outputs are
+    the global model and the raw error-feedback residual.  A fully closed
+    round takes the branch that returns ``w_global`` and a zero residual
+    — it never pays the merge collective's latency.  The refresh and the
+    error-feedback select are masked selects after the cond: on a closed
+    round every gate is shut, so both return their inputs bit for bit, and
+    on an open round they are :func:`hermes_merge`'s own.  The pod
+    parameters are therefore only read by the cond, never passed through
+    it, so the compiler has no reason to copy them (or the donated
+    residual) around it.
 
     ``use_kernel=None`` resolves the kernel-vs-jnp dispatch from
     ``cfg.kernel_dispatch`` and the ``REPRO_WIRE_KERNEL`` env var
@@ -515,37 +546,32 @@ def hermes_round(pod_params: Tree, gup_state: Tree, pod_losses: jnp.ndarray,
         use_kernel = resolve_kernel_dispatch(
             getattr(cfg, "kernel_dispatch", "auto"))
     gates, new_gup, any_push = _gate(gup_state, pod_losses, cfg, live, rng)
-    err_in = error if cfg.error_feedback else None
-    # hermes_merge tracks a residual for every non-"none" format (lossless
-    # ones just carry exact zeros), so the closed branch must mirror that
-    # exactly or lax.cond's output trees diverge.
-    compressed = cfg.compression != "none"
+    track_error = cfg.error_feedback
+    err_in = error if track_error else None
+    # every non-"none" format tracks a residual (lossless ones carry exact
+    # zeros), so the closed branch must return one of the same tree or
+    # lax.cond's output trees diverge
+    residual_like = cfg.compression != "none" and track_error
 
     def _open(args):
         pods, wg, err = args
-        new_pods, new_global, new_error, _ = hermes_merge(
-            pods, gates, pod_losses, wg, L,
-            compression=cfg.compression, error=err,
-            use_kernel=use_kernel, rng=rng,
-            track_error=cfg.error_feedback,
-            mesh=mesh, pod_axis=pod_axis)
-        return new_pods, new_global, new_error
+        return _push(pods, gates, pod_losses, wg, L,
+                     compression=cfg.compression, error=err,
+                     use_kernel=use_kernel, rng=rng, track_error=track_error,
+                     mesh=mesh, pod_axis=pod_axis)
 
     def _closed(args):
-        pods, wg, err = args
-        # A compressed error-tracking round with no residual yet starts one
-        # at zero so both cond branches return the same pytree structure.
-        if compressed and cfg.error_feedback and err is None:
-            err = jax.tree.map(jnp.zeros_like, pods)
-        return pods, wg, err
+        pods, wg, _ = args
+        return wg, (jax.tree.map(jnp.zeros_like, pods) if residual_like
+                    else None)
 
-    new_pods, new_global, new_error = jax.lax.cond(
+    new_global, residual = jax.lax.cond(
         any_push, _open, _closed, (pod_params, w_global, err_in))
     return {
-        "pod_params": new_pods,
+        "pod_params": _refresh(pod_params, new_global, gates),
         "w_global": new_global,
         "gup": new_gup,
-        "error": new_error,
+        "error": _keep_error(gates, residual, err_in, track_error),
         "gates": gates,
         "any_push": any_push,
     }
@@ -626,8 +652,9 @@ def hermes_dispatch(pod_params: Tree, gup_state: Tree,
     if compressed:
         def _open(args):
             pods, wg, err = args
-            _, payloads, new_error = _encode(
+            _, payloads, residual = _encode(
                 pods, wg, gates, err, fmt, rng=rng, track_error=track_error)
+            new_error = _keep_error(gates, residual, err, track_error)
             shipped = gather_payloads(payloads, mesh, axis=pod_axis,
                                       n_pods=n_pods)
             return shipped, new_error
@@ -1053,9 +1080,10 @@ def hermes_cluster_merge(pod_params: Tree, gates: jnp.ndarray,
     # Lossless wire: unlike the flat merge (which ships gate-zeroed
     # replicas), the two-tier path ships the DELTA uniformly for all
     # formats — the partial-sum identity needs r_i, not w_i.
-    delta, payloads, new_error = _encode(
+    delta, payloads, residual = _encode(
         pod_params, w_global, gates, error, fmt, rng=rng,
         track_error=track_error, lossy=compression != "none")
+    new_error = _keep_error(gates, residual, error, track_error)
 
     # Fast tier: every cluster gathers its own members' payload rows.
     payloads = gather_payloads_tiered(payloads, mesh, axis=pod_axis,
@@ -1189,9 +1217,10 @@ def hermes_cluster_dispatch(pod_params: Tree, gup_state: Tree,
 
     def _open(args):
         pods, wg, err = args
-        delta, payloads, new_error = _encode(
+        delta, payloads, residual = _encode(
             pods, wg, gates, err, fmt, rng=rng, track_error=track_error,
             lossy=compressed)
+        new_error = _keep_error(gates, residual, err, track_error)
         shipped = gather_payloads_tiered(payloads, mesh, axis=pod_axis,
                                          keep=cluster_axis, n_rows=n_pods)
         partials = _cluster_partials(wg, shipped, delta, fmt, w2,

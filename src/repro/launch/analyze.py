@@ -4,7 +4,9 @@ Each entry-point executable is lowered on a small forced-device CPU pod
 mesh and checked against the registered invariant rules
 (:mod:`repro.analysis`, DESIGN.md §9):
 
-* ``hermes_round`` (open + closed) — the synchronous Level-B round.
+* ``hermes_round`` (open + closed) — the synchronous Level-B round, and
+  the executable ``train_hermes`` runs it as (``make_round_jit``), whose
+  donated pods, global model and residual must alias in place.
 * ``hermes_dispatch`` / ``hermes_commit`` — the async pipelined halves,
   including the commit's donation contract (``make_async_round_jits``).
 * ``elastic_shrink`` / ``elastic_grow`` — a *real* 4 -> 3 -> 4 pod resize
@@ -63,7 +65,9 @@ from repro.dist.wire import payload_buffer_spec, wire_operand_specs
 from repro.launch.elastic import elastic_grow, elastic_shrink
 from repro.launch.mesh import arch_rules, make_pod_mesh
 from repro.launch.steps import build_setup
-from repro.launch.train import make_async_round_jits, train_hermes
+from repro.launch.train import (
+    make_async_round_jits, make_round_jit, train_hermes,
+)
 
 Tree = Any
 
@@ -89,6 +93,14 @@ def _toy(n: int = N_PODS):
 def _sds(tree: Tree) -> Tree:
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+
+def _placed(tree: Tree, shardings: Tree) -> Tree:
+    """Abstract args carrying their shardings, so a production jit lowers
+    with its own donation and output contract."""
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        _sds(tree), shardings)
 
 
 def _round_shardings(mesh, pods, gup, wg):
@@ -157,6 +169,37 @@ def check_hermes_round(mode: Optional[str] = None) -> List[Report]:
     return [rep_open, rep_closed]
 
 
+def check_round_jit(mode: Optional[str] = None) -> List[Report]:
+    """The round as ``train_hermes`` jits it (``make_round_jit``): it ships
+    the billed wire, and its donated pods, global model and error residual
+    (a tree exactly when the wire is lossy with error feedback) each alias
+    their output in the ``input_output_alias`` header."""
+    cfg = _cfg(mode)
+    mesh = make_pod_mesh(N_PODS)
+    pods, wg = _toy()
+    gup = hermes_pod_state(cfg, N_PODS)
+    pod_sh, gup_sh, rep, rep_tree = _round_shardings(mesh, pods, gup, wg)
+    has_error = cfg.compression != "none" and cfg.error_feedback
+    args = (_placed(pods, pod_sh), _placed(gup, gup_sh),
+            jax.ShapeDtypeStruct((N_PODS,), jnp.float32, sharding=rep),
+            _placed(wg, rep_tree),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+            _placed(pods, pod_sh) if has_error else None,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep))
+    with mesh:
+        lowered = make_round_jit(cfg, mesh).lower(*args)
+    donated = donated_param_numbers(args, (0, 3, 5))
+    names = {0: "pod_params", 3: "w_global"}
+    if has_error:
+        names[5] = "error"
+    return [analyze(
+        lowered,
+        rules=[_placement_rule(mesh, wg, cfg.compression, N_PODS),
+               DonationAliasing({name: range(*donated[k])
+                                 for k, name in names.items()})],
+        label=f"hermes_round_jit[{cfg.compression}]")]
+
+
 def check_async_halves(mode: Optional[str] = None) -> List[Report]:
     """Dispatch carries the gather; commit is collective-free and its
     donations (pod_params + pending) hold in the alias header."""
@@ -196,15 +239,9 @@ def check_async_halves(mode: Optional[str] = None) -> List[Report]:
     }
     _, commit_jit = make_async_round_jits(cfg, mesh)
     # lower the PRODUCTION commit executable (donation contract included)
-    # by carrying the shardings on the abstract args themselves
-    shard = lambda t, sh: jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        _sds(t), sh)
-    c_args = (shard(pods, pod_sh),
-              jax.tree.map(
-                  lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                                 sharding=rep), pending),
-              shard(wg, rep_tree))
+    c_args = (_placed(pods, pod_sh),
+              _placed(pending, jax.tree.map(lambda _: rep, pending)),
+              _placed(wg, rep_tree))
     with mesh:
         c_lowered = commit_jit.lower(*c_args)
     donated = donated_param_numbers(c_args, (0, 1))
@@ -534,6 +571,7 @@ def main() -> None:
 
     reports: List[Report] = []
     reports += check_hermes_round(args.mode)
+    reports += check_round_jit(args.mode)
     reports += check_async_halves(args.mode)
     reports += check_admission(args.mode)
     reports += check_elastic(args.mode)

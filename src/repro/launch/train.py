@@ -139,11 +139,16 @@ def make_pod_step(cfg: ModelConfig, optimizer, mesh=None):
 def make_round_jit(hcfg: HermesConfig, mesh=None):
     """The synchronous round as one executable:
     ``(pod_params, gup, pod_losses, w_global, L, error, rng) -> dict``
-    (``hermes_cluster_round``'s).  The pod params and the error residual
-    are donated (the round returns their successors); the residual is a
-    tree exactly when the wire is lossy with error feedback, else None.
-    With a ``mesh`` the pod-stacked outputs stay pod-sharded and the
-    global model replicated."""
+    (``hermes_cluster_round``'s).  The pod params, the global model and
+    the error residual are donated: the round returns their successors and
+    the loop never reads the old ones, so each aliases its output in place
+    (``launch/analyze.py: check_round_jit``).  The round's ``lax.cond``
+    carries only the global model and the residual; the refresh and the
+    error-feedback select are masked selects after it, written into the
+    donated pod and residual buffers.  The residual is a tree exactly when
+    the wire is lossy with error feedback, else None.  With a ``mesh`` the
+    pod-stacked outputs stay pod-sharded and the global model
+    replicated."""
     pod_sh, rep = pod_shardings(mesh)
     has_error = hcfg.compression != "none" and hcfg.error_feedback
     placed = {}
@@ -156,7 +161,7 @@ def make_round_jit(hcfg: HermesConfig, mesh=None):
         lambda pod_params, gup, pod_losses, w_global, L, error, rng:
         hermes_cluster_round(pod_params, gup, pod_losses, w_global, L,
                              cfg=hcfg, error=error, rng=rng, mesh=mesh),
-        donate_argnums=(0, 5), **placed)
+        donate_argnums=(0, 3, 5), **placed)
 
 
 def make_async_round_jits(hcfg: HermesConfig, mesh=None):

@@ -9,13 +9,17 @@ the chip.  Nothing runs, so these tests say nothing about results or times.
   2-pod payloads, called on the kernel modules with ``interpret=False``;
 * the Hermes round placed on four described chips, with the kernels on,
   which must keep each kernel per device (a Mosaic kernel cannot be
-  partitioned automatically).
+  partitioned automatically);
+* the Hermes cell's synchronous round on one described chip, whose
+  compiler-added ``copy`` instructions are counted: the round's
+  ``lax.cond`` must not make the compiler copy the donated model around it.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 from __future__ import annotations
 
+import re
 from functools import partial
 
 import numpy as np
@@ -143,3 +147,104 @@ def test_placed_round_carries_every_phase_scope(placed_round_text):
     from repro.utils import tracing
     for s in tracing.SCOPES:
         assert f"/{s}/" in placed_round_text, s
+
+
+# The cell's round as its parent compiled it (the whole merge, refresh and
+# error-feedback select under the cond; pods and residual donated, the
+# global model not), counted by ``_round_copies`` below: 156 copies of
+# 29,660,196,992 bytes on the open path, 49 of them in the entry.
+PARENT_OPEN_COPY_BYTES = 29_660_196_992
+_COPY_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\w+\[[\d,]*\])\S*\s+copy\(")
+_CALLEE_RE = re.compile(r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)")
+_BRANCHES_RE = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _round_copies(text):
+    """``(entry, open path)``: the dims and bytes of each ``copy``
+    instruction in the round's ENTRY computation, and in every computation
+    an open round runs (ENTRY, the cond's open branch, the loops under it).
+    A copy inside a fusion is a layout change of that fusion's own pass,
+    not a pass over memory, so fusion bodies are not walked."""
+    from repro.analysis.hlo_parse import (
+        shape_bytes, shape_dims, split_computations,
+    )
+    comps, entry = split_computations(text)
+
+    def copies(name):
+        return [(tuple(shape_dims(m.group(1))), shape_bytes(m.group(1)))
+                for m in map(_COPY_RE.match, comps[name]) if m]
+
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if " fusion(" in line:
+                continue
+            todo += _CALLEE_RE.findall(line)
+            branches = _BRANCHES_RE.search(line)
+            if branches:
+                names = [c.strip().lstrip("%")
+                         for c in branches.group(1).split(",")]
+                # lax.cond's index 0 is the false (closed) branch
+                todo += names[1:] if name == entry else names
+    return copies(entry), [c for n in seen for c in copies(n)]
+
+
+@pytest.fixture(scope="module")
+def cell_round(topo, one_chip):
+    """The Hermes cell's synchronous round as ``train_hermes`` builds it
+    (``make_round_jit``): phi3-mini widths at one layer, 2 pods, int4 with
+    error feedback, the jnp wire; compiled for one described chip.
+    Returns its HLO text and the dims of its donated leaves."""
+    from repro.config import HermesConfig
+    from repro.dist.hermes_sync import hermes_pod_state
+    from repro.launch.train import _preset, make_round_jit
+    from repro.models import init_lm
+
+    hcfg = HermesConfig(alpha=-0.5, beta=0.1, lam=1, eta=1.0, window=10,
+                        compression="int4", error_feedback=True,
+                        kernel_dispatch="off")
+    params = jax.eval_shape(
+        lambda k: init_lm(_preset("phi3-mini-3.8b", 1), k)[0],
+        jax.random.PRNGKey(0))
+
+    def sds(tree, lead=()):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            lead + a.shape, a.dtype, sharding=one_chip), tree)
+
+    def scalar(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pods = sds(params, (N_PODS,))
+    gup = sds(jax.eval_shape(lambda: hermes_pod_state(hcfg, N_PODS)))
+    args = (pods, gup, scalar((N_PODS,), jnp.float32), sds(params),
+            scalar((), jnp.float32), pods, scalar((2,), jnp.uint32))
+    text = make_round_jit(hcfg).lower(*args).compile().as_text()
+    return text, {tuple(a.shape) for a in jax.tree.leaves((pods, params))}
+
+
+def test_round_does_not_copy_the_model_around_its_cond(cell_round):
+    """The cond carries only the global model and the residual, so no pod,
+    residual or global-model leaf is copied in the round's entry, and the
+    open path copies at most 40% of the bytes the parent formulation did
+    (what is left: the int4 wire's layout changes)."""
+    text, donated = cell_round
+    entry, open_path = _round_copies(text)
+    in_entry = [c for c in entry if c[0] in donated]
+    assert not in_entry, in_entry
+    moved = sum(b for _, b in open_path)
+    assert moved <= 0.4 * PARENT_OPEN_COPY_BYTES, (
+        moved, len(open_path), PARENT_OPEN_COPY_BYTES)
+
+
+def test_cell_round_carries_its_phase_scopes(cell_round):
+    """The round's phases keep their scopes through the chip's compiler
+    (the unplaced round ships nothing, so it has no gather)."""
+    from repro.utils import tracing
+    text, _ = cell_round
+    for s in (tracing.GATE, tracing.ENCODE, tracing.MERGE, tracing.REFRESH):
+        assert f"/{s}/" in text, s

@@ -6,7 +6,7 @@ import pytest
 
 from repro.config import HermesConfig
 from repro.dist.hermes_sync import (
-    hermes_merge, hermes_pod_state, hermes_round,
+    _gate, hermes_merge, hermes_pod_state, hermes_round,
 )
 
 
@@ -231,6 +231,100 @@ def test_closed_round_skips_merge_and_stays_bit_identical(monkeypatch):
                                   np.asarray(pods["w"]))
     # the error-feedback state starts at zero on closed rounds
     assert float(jnp.abs(out["error"]["w"]).max()) == 0.0
+
+
+def _cond_around_merge(pods, gup, losses, wg, L, cfg, *, live=None,
+                       error=None, rng=None):
+    """The round with the whole merge under the cond: ``hermes_merge``
+    (refresh and error-feedback select included) in the open branch, the
+    inputs passed through the closed one."""
+    gates, new_gup, any_push = _gate(gup, losses, cfg, live, rng)
+    err_in = error if cfg.error_feedback else None
+
+    def _open(args):
+        p, w, e = args
+        new_p, new_w, new_e, _ = hermes_merge(
+            p, gates, losses, w, L, compression=cfg.compression, error=e,
+            rng=rng, track_error=cfg.error_feedback)
+        return new_p, new_w, new_e
+
+    def _closed(args):
+        p, w, e = args
+        if cfg.compression != "none" and cfg.error_feedback and e is None:
+            e = jax.tree.map(jnp.zeros_like, p)
+        return p, w, e
+
+    new_p, new_w, new_e = jax.lax.cond(any_push, _open, _closed,
+                                       (pods, wg, err_in))
+    return {"pod_params": new_p, "w_global": new_w, "gup": new_gup,
+            "error": new_e, "gates": gates, "any_push": any_push}
+
+
+# test-round losses after two warm-up rounds at 1.0 and 1.2: 0.5 and 0.6
+# open a gate (z far below alpha), 2.0 keeps it shut
+_ROUND_KINDS = {
+    "all_open": ([0.5, 0.5, 0.6], None),
+    "some_open": ([0.5, 2.0, 0.6], None),
+    "all_closed": ([2.0, 2.0, 2.0], None),
+    "dead_pod": ([0.5, 0.5, 0.6], [True, False, True]),
+}
+
+
+@pytest.mark.parametrize("compression", ["int4", "none"])
+@pytest.mark.parametrize("kind", sorted(_ROUND_KINDS))
+def test_round_matches_cond_around_merge(kind, compression):
+    """Narrowing the round's cond to the merge changes no bit: pods,
+    global model, residual, GUP state and gates equal those of the round
+    whose cond wraps ``hermes_merge`` whole, jitted both; a fully closed
+    round returns its inputs unchanged."""
+    cfg = HermesConfig(alpha=-0.5, beta=0.1, lam=1, window=10,
+                       compression=compression,
+                       error_feedback=compression != "none")
+    n = 3
+    key = jax.random.PRNGKey(21)
+    wg = {"w": jax.random.normal(jax.random.fold_in(key, 0), (8, 512)),
+          "b": jax.random.normal(jax.random.fold_in(key, 1), (7,))}
+    pods = jax.tree.map(lambda g: g[None] + 0.05 * jax.random.normal(
+        jax.random.fold_in(key, 2), (n,) + g.shape), wg)
+    error = (jax.tree.map(lambda p: 1e-3 * jax.random.normal(
+        jax.random.fold_in(key, 3), p.shape), pods)
+        if cfg.error_feedback else None)
+    losses, live = _ROUND_KINDS[kind]
+    if live is not None:
+        # the dead pod's replica has diverged: it must poison nothing
+        pods = jax.tree.map(lambda p: p.at[1].set(jnp.nan), pods)
+        live = jnp.asarray(live)
+    gup = hermes_pod_state(cfg, n)
+    for warm in (1.0, 1.2):
+        _, gup, _ = _gate(gup, jnp.full((n,), warm, jnp.float32), cfg,
+                          None, None)
+    args = (pods, gup, jnp.asarray(losses, jnp.float32), wg,
+            jnp.float32(1.1))
+    rng = jax.random.PRNGKey(5)
+    new = jax.jit(lambda p, g, l, w, L, e: hermes_round(
+        p, g, l, w, L, cfg, live=live, error=e, rng=rng))(*args, error)
+    old = jax.jit(lambda p, g, l, w, L, e: _cond_around_merge(
+        p, g, l, w, L, cfg, live=live, error=e, rng=rng))(*args, error)
+
+    want_gates = {"all_open": [1, 1, 1], "some_open": [1, 0, 1],
+                  "all_closed": [0, 0, 0], "dead_pod": [1, 0, 1]}[kind]
+    np.testing.assert_array_equal(np.asarray(new["gates"]),
+                                  np.asarray(want_gates, bool))
+    assert set(new) == set(old)
+    for k in new:
+        assert (jax.tree.structure(new[k]) == jax.tree.structure(old[k])), k
+        for a, b in zip(jax.tree.leaves(new[k]), jax.tree.leaves(old[k])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=k)
+    if kind == "all_closed":
+        for k, given in (("pod_params", pods), ("w_global", wg),
+                         ("error", error)):
+            for a, b in zip(jax.tree.leaves(new[k]), jax.tree.leaves(given)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=k)
+    else:
+        for leaf in jax.tree.leaves(new["w_global"]):
+            assert bool(jnp.all(jnp.isfinite(leaf)))
 
 
 # ---------------------------------------------------------------------------
