@@ -47,11 +47,34 @@ class MoEConfig:
     shared_ff: int = 0  # hidden size of the shared expert(s), 0 = same as expert_ff
     router_jitter: float = 0.0
     capacity_factor: float = 1.25
+    # top-k weights renormalised to sum to one (False: the router's softmax
+    # probabilities themselves, as DeepSeek-V2-Lite's scaling factor of 1)
+    norm_topk_prob: bool = True
+    # every assignment reaches its expert: grouped products over the
+    # sorted assignments, no capacity (``models/moe.py: moe_dropless``)
+    dropless: bool = False
+    # sequence-wise balance-loss coefficient (DeepSeek-V2's ``seq_aux``
+    # term, one per expert layer, added to the training loss); 0 = none
+    aux_loss_alpha: float = 0.0
+    # the chip's share under expert parallelism: routed experts
+    # ``first_held .. first_held + num_held - 1`` live here (0 = all).  The
+    # router still scores every expert; assignments to absent experts add
+    # nothing on this chip.
+    num_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
 
     def validate(self) -> None:
         assert self.num_experts >= 1
         assert 1 <= self.top_k <= self.num_experts
         assert self.expert_ff >= 1
+        assert 0 <= self.first_held and \
+            self.first_held + self.held <= self.num_experts
+        # the capacity path stacks every expert
+        assert self.dropless or self.held == self.num_experts
 
 
 @dataclass(frozen=True)
@@ -62,6 +85,24 @@ class MLAConfig:
     q_lora_rank: int = 0  # 0 = full-rank queries (v2-lite uses full-rank q)
     rope_head_dim: int = 64  # decoupled RoPE key/query head dim
     v_head_dim: int = 0  # 0 = same as nope head dim
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071; DeepSeek-V2's ``rope_scaling``).
+
+    Rotary frequencies above ``beta_fast`` rotations over the original
+    context keep their value, those below ``beta_slow`` are divided by
+    ``factor``, with a linear ramp between; cos/sin carry
+    mscale(mscale) / mscale(mscale_all_dim), and an MLA softmax scale
+    mscale(mscale_all_dim)**2, where mscale(m) = 0.1 m ln(factor) + 1."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -91,12 +132,16 @@ class ModelConfig:
     qk_norm: bool = False
     attn_window: int = 0  # 0 = full/global attention; >0 = local sliding window
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     use_rope: bool = True
     # --- block options ------------------------------------------------------
     mlp_kind: str = "swiglu"  # swiglu | gelu | relu_sq
     norm_kind: str = "rmsnorm"  # rmsnorm | layernorm
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    # leading layers that keep a dense MLP of width d_ff before the expert
+    # layers (DeepSeek-V2's first_k_dense_replace)
+    first_dense_layers: int = 0
     mla: Optional[MLAConfig] = None
     recurrent: Optional[RecurrentConfig] = None
     # --- enc-dec ------------------------------------------------------------
@@ -140,6 +185,8 @@ class ModelConfig:
                 f"{self.name}: heads {self.num_heads} not divisible by kv {self.num_kv_heads}")
         if self.moe is not None:
             self.moe.validate()
+        assert 0 <= self.first_dense_layers < self.num_layers
+        assert self.moe is not None or self.first_dense_layers == 0
         if self.recurrent is not None:
             assert self.recurrent.kind in ("rwkv6", "rglru")
 
@@ -166,8 +213,8 @@ class ModelConfig:
                 return p
             return d * n_q + 2 * d * n_kv + n_q * d
 
-        def mlp_params(active: bool) -> int:
-            if self.moe is not None:
+        def mlp_params(active: bool, dense: bool = False) -> int:
+            if self.moe is not None and not dense:
                 me = self.moe
                 per_expert = 3 * d * me.expert_ff if self.mlp_kind == "swiglu" else 2 * d * me.expert_ff
                 shared_ff = me.shared_ff or me.expert_ff
@@ -202,7 +249,10 @@ class ModelConfig:
             total += n_rec * (rec_params() + mlp_params(active_only))
             total += n_attn * (attn_params() + mlp_params(active_only))
         else:
-            total += layers * (attn_params() + mlp_params(active_only))
+            n_dense = self.first_dense_layers
+            total += layers * attn_params()
+            total += n_dense * mlp_params(active_only, dense=True)
+            total += (layers - n_dense) * mlp_params(active_only)
         return int(total)
 
 

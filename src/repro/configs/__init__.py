@@ -23,14 +23,19 @@ _REGISTRY: Dict[str, str] = {
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "grok-1-314b": "grok1_314b",
     "deepseek-v2-lite-16b": "deepseek_v2_lite_16b",
+    # one chip's share of deepseek-v2-lite-16b (8-way expert parallelism)
+    "deepseek-v2-lite-ep8": "deepseek_v2_lite_ep8",
     "recurrentgemma-2b": "recurrentgemma_2b",
     # the paper's own evaluation models
     "mnist-cnn": "mnist_cnn",
     "cifar-alexnet": "cifar_alexnet",
 }
 
-ASSIGNED_ARCHS: List[str] = [k for k in _REGISTRY if k not in ("mnist-cnn", "cifar-alexnet")]
 PAPER_ARCHS: List[str] = ["mnist-cnn", "cifar-alexnet"]
+# deployment shares of an assigned architecture, not architectures
+SHARES: List[str] = ["deepseek-v2-lite-ep8"]
+ASSIGNED_ARCHS: List[str] = [k for k in _REGISTRY
+                             if k not in PAPER_ARCHS + SHARES]
 
 
 def _module(arch: str):

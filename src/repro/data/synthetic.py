@@ -54,19 +54,32 @@ def make_image_dataset(n: int, image_shape: Tuple[int, int, int],
 
 def make_lm_dataset(n_tokens: int, vocab: int, *, seed: int = 0,
                     order: int = 2) -> np.ndarray:
-    """Markov token stream with Zipf unigram marginals; (n_tokens,) int32."""
+    """Markov token stream with Zipf unigram marginals; (n_tokens,) int32.
+
+    Each token after the first is, with chance 0.6, its predecessor's fixed
+    successor, else a fresh Zipf(1.1) draw.  A token takes one double to
+    decide and a second for a fresh draw (inverse CDF, as
+    ``rng.choice(vocab, p=...)`` draws), so the doubles are drawn in bulk
+    and walked in order: the stream is that of drawing them one by one."""
     rng = np.random.default_rng(seed)
     base = 1.0 / np.arange(1, vocab + 1) ** 1.1
     base /= base.sum()
-    # sparse bigram boosts for learnable structure
+    cdf = base.cumsum()
+    cdf /= cdf[-1]
     toks = np.empty(n_tokens, np.int32)
-    toks[0] = rng.choice(vocab, p=base)
-    boost = rng.integers(0, vocab, size=vocab)  # deterministic successor bias
+    toks[0] = cdf.searchsorted(rng.random(), side="right")
+    # sparse bigram boosts for learnable structure: a deterministic successor
+    boost = rng.integers(0, vocab, size=vocab).tolist()
+    u = rng.random(2 * max(n_tokens - 1, 0))
+    keep = (u < 0.6).tolist()
+    fresh = cdf.searchsorted(u, side="right").tolist()
+    prev, j = int(toks[0]), 0
     for i in range(1, n_tokens):
-        if rng.random() < 0.6:
-            toks[i] = boost[toks[i - 1]]
+        if keep[j]:
+            prev, j = boost[prev], j + 1
         else:
-            toks[i] = rng.choice(vocab, p=base)
+            prev, j = fresh[j + 1], j + 2
+        toks[i] = prev
     return toks
 
 

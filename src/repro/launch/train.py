@@ -231,6 +231,34 @@ def make_batches(tokens: np.ndarray, batch: int, seq: int, rng,
         yield {"tokens": jnp.asarray(x), "targets": jnp.asarray(y)}
 
 
+def single_step_fn(cfg: ModelConfig, optimizer):
+    """``train_single``'s step, before jit: ``(state, batch) -> (state,
+    loss)``.  An expert model's state also carries ``"expert_load"``, the
+    assignments each expert layer's held experts received in the step,
+    (expert layers, held) int32, so the log line reads it at its fetch."""
+    def step_fn(state, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: lm_loss(p, batch, cfg, with_aux=True),
+            has_aux=True)(state["params"])
+        p, o = optimizer.apply(state["params"], grads, state["opt"])
+        new = {"params": p, "opt": o, "step": state["step"] + 1}
+        if aux is not None:
+            new["expert_load"] = aux["load"]
+        return new, loss
+    return step_fn
+
+
+def single_state(cfg: ModelConfig, optimizer, params) -> Dict:
+    """``train_single``'s initial state for ``params``."""
+    state = {"params": params, "opt": optimizer.init(params),
+             "step": jnp.int32(0)}
+    if cfg.moe is not None:
+        state["expert_load"] = jnp.zeros(
+            (cfg.num_layers - cfg.first_dense_layers, cfg.moe.held),
+            jnp.int32)
+    return state
+
+
 def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                  opt_cfg: OptimizerConfig, ckpt_dir: Optional[str] = None,
                  restore: bool = False, log_every: int = 20,
@@ -240,8 +268,7 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     tokens = make_lm_dataset(batch * seq * 40 + 1, cfg.vocab_size, seed=seed)
     optimizer = make_optimizer(opt_cfg)
     params, _ = init_lm(cfg, jax.random.PRNGKey(seed))
-    state = {"params": params, "opt": optimizer.init(params),
-             "step": jnp.int32(0)}
+    state = single_state(cfg, optimizer, params)
     start_step = 0
     ck = Checkpointer(ckpt_dir) if ckpt_dir else None
     if ck and restore:
@@ -257,14 +284,11 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     # the old state is dead the moment the step returns the new one, so
     # donate it: peak memory stays one state + transients, and the
     # donation-aliasing rule (repro.analysis) can pin the alias header
-    @partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state, batch):
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(p, batch, cfg))(state["params"])
-        p, o = optimizer.apply(state["params"], grads, state["opt"])
-        return {"params": p, "opt": o, "step": state["step"] + 1}, loss
+    step_fn = partial(jax.jit, donate_argnums=(0,))(
+        single_step_fn(cfg, optimizer))
 
     losses = []
+    load = ""
     log_times = []  # (step, wall s since loop start), taken after a sync
     t0 = time.time()
     for i in range(start_step, steps):
@@ -274,6 +298,10 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
         with span(tracing.STEP, step=i):
             state, loss = step_fn(state, batch_i)
         with span(tracing.FETCH, step=i):
+            if log and "expert_load" in state:
+                loss, counts = jax.device_get((loss, state["expert_load"]))
+                load = (f" expert load max/mean "
+                        f"{counts.max() / max(counts.mean(), 1e-9):.3f}")
             losses.append(float(loss))
             if log:
                 jax.block_until_ready(state)
@@ -283,7 +311,7 @@ def train_single(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                 print(f"step {i+1:5d} loss "
                       f"{np.mean(losses[-log_every:]):.4f} "
                       f"({(i + 1 - start_step) / (time.time() - t0):.2f} "
-                      f"it/s)", flush=True)
+                      f"it/s){load}", flush=True)
         if ck and (i + 1) % 100 == 0:
             with span(tracing.CKPT, step=i):
                 ck.save(state, i + 1)

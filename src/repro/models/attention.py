@@ -19,9 +19,19 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.dist.sharding import AxisRules, constrain
-from repro.models.layers import dense_init, ones_init, apply_rope, rms_norm_vec
+from repro.models.layers import (
+    apply_rope, dense_init, ones_init, rms_norm_vec, yarn_mscale,
+)
+from repro.utils import tracing
 
 NEG_INF = -1e30
+# The blocked path's forward keeps every chunk's float32 scores for the
+# backward pass up to this many bytes per call; past it the backward
+# recomputes them chunk by chunk.  phi3-mini at 2 x 2048 tokens and 32
+# heads keeps its 1 GiB; at 2 x 4096 tokens and 16 heads
+# (DeepSeek-V2-Lite) they are 2 GiB a layer, and its five-layer step
+# compiled for a 16 GiB v5e needs 19.0 GiB with them kept, 12.6 without.
+SAVED_SCORES_MAX_BYTES = 1 << 30
 
 
 # ---------------------------------------------------------------------------
@@ -65,6 +75,9 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     Scans KV chunks for each query chunk, carrying (acc, row_max, row_sum).
     Produces identical results to :func:`naive_attention` (fp32 accumulate).
+    Where every chunk's float32 scores would take more than
+    ``SAVED_SCORES_MAX_BYTES``, the backward pass recomputes each chunk's
+    scores instead of keeping them from the forward pass.
     """
     B, Sq, H, D = q.shape
     Skv, K = k.shape[1], k.shape[2]
@@ -86,6 +99,7 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
         kpos = jnp.pad(kpos, (0, pk), constant_values=-1)
     nq, nk = q.shape[1] // q_chunk, k.shape[1] // kv_chunk
+    saved = 4 * B * H * q.shape[1] * k.shape[1]
 
     qc = q.reshape(B, nq, q_chunk, K, G, D).astype(jnp.float32)
     kc = k.reshape(B, nk, kv_chunk, K, D).astype(jnp.float32)
@@ -118,7 +132,9 @@ def blocked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         acc0 = jnp.zeros((B, K, G, q_chunk, Dv), jnp.float32)
         mx0 = jnp.full((B, K, G, q_chunk), NEG_INF, jnp.float32)
         sm0 = jnp.zeros((B, K, G, q_chunk), jnp.float32)
-        (acc, mx, sm), _ = jax.lax.scan(kv_step, (acc0, mx0, sm0),
+        step = kv_step if saved <= SAVED_SCORES_MAX_BYTES else (
+            jax.checkpoint(kv_step))
+        (acc, mx, sm), _ = jax.lax.scan(step, (acc0, mx0, sm0),
                                         jnp.arange(nk))
         out = acc / jnp.maximum(sm, 1e-30)[..., None]  # (B,K,G,qc,D)
         return None, out
@@ -324,13 +340,23 @@ def _mla_qkv(p, x, cfg: ModelConfig, positions):
     nope = cfg.resolved_head_dim
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     c_kv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt))
     c_kv = rms_norm_vec(c_kv, p["kv_norm"])
     k_rope = jnp.einsum("bsd,dk->bsk", x, p["w_kr"].astype(dt))
     k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+                        cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of MLA: 1/sqrt(nope + rope head dims), times YaRN's
+    mscale(mscale_all_dim) squared where the rope is scaled."""
+    scale = (cfg.resolved_head_dim + cfg.mla.rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
 
 
 def _mla_expand(p, c_kv, k_rope, dt):
@@ -345,18 +371,16 @@ def _mla_expand(p, c_kv, k_rope, dt):
 
 def apply_mla(p, x: jnp.ndarray, cfg: ModelConfig, rules: Optional[AxisRules],
               *, positions: jnp.ndarray, impl: str = "auto") -> jnp.ndarray:
-    m = cfg.mla
     dt = x.dtype
-    nope = cfg.resolved_head_dim
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, positions)
-    k, v = _mla_expand(p, c_kv, k_rope, dt)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    q = constrain(q, rules, "batch", None, "act_heads", None)
-    scale = (nope + m.rope_head_dim) ** -0.5
-    out = attention_impl(q, k, v, causal=True, q_positions=positions,
-                         impl=impl, scale=scale)
-    out = constrain(out, rules, "batch", None, "act_heads", None)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    with tracing.scope(tracing.MLA):
+        q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, positions)
+        k, v = _mla_expand(p, c_kv, k_rope, dt)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        q = constrain(q, rules, "batch", None, "act_heads", None)
+        out = attention_impl(q, k, v, causal=True, q_positions=positions,
+                             impl=impl, scale=mla_scale(cfg))
+        out = constrain(out, rules, "batch", None, "act_heads", None)
+        return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -373,9 +397,7 @@ def decode_mla(p, x: jnp.ndarray, cache: Dict[str, jnp.ndarray],
                cfg: ModelConfig, rules: Optional[AxisRules], *,
                pos: jnp.ndarray, impl: str = "auto"):
     """Stateful MLA: x: (B, T, d) at absolute start position `pos`."""
-    m = cfg.mla
     dt = x.dtype
-    nope = cfg.resolved_head_dim
     positions = pos + jnp.arange(x.shape[1])
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, x, cfg, positions)
     ckv = jax.lax.dynamic_update_slice_in_dim(
@@ -387,8 +409,7 @@ def decode_mla(p, x: jnp.ndarray, cache: Dict[str, jnp.ndarray],
     new_cache = {"c_kv": ckv, "k_rope": ckr, "pos": cpos}
     k, v = _mla_expand(p, ckv.astype(dt), ckr.astype(dt), dt)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = (nope + m.rope_head_dim) ** -0.5
     out = attention_impl(q, k, v, causal=True, q_positions=positions,
-                         kv_positions=cpos, impl=impl, scale=scale)
+                         kv_positions=cpos, impl=impl, scale=mla_scale(cfg))
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
     return out, new_cache

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -162,22 +163,57 @@ def apply_mlp(p: Any, x: jnp.ndarray, cfg: ModelConfig,
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1 m ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Inverse rotary frequencies; with a ``YarnConfig``, YaRN's: the
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep theta's frequency, those that turn fewer than
+    ``beta_slow`` times take it over ``factor``, with a linear ramp
+    between (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    base = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim))
+    if scaling is None:
+        return base
+
+    def dim_of(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(dim_of(scaling.beta_fast)), 0)
+    hi = min(math.ceil(dim_of(scaling.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - lo)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (base / scaling.factor * ramp + base * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
     """x: (..., seq, heads, head_dim); positions: (..., seq).
 
     Angles/sin/cos are fp32 (tiny (seq, hd/2) tables); the rotation itself
     runs in the compute dtype so no fp32 copy of the full q/k tensor is
     materialized (sub-ULP difference vs the fp32 rotation for bf16 inputs).
+    ``scaling`` (a ``YarnConfig``) gives YaRN's frequencies, and cos/sin
+    carry mscale(mscale) / mscale(mscale_all_dim).
     """
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta))  # (hd/2,)
+    freqs = jnp.asarray(rope_freqs(hd, theta, scaling))  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
-    cos = jnp.cos(angles)[..., :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., :, None, :].astype(x.dtype)
+    m = 1.0 if scaling is None else (
+        yarn_mscale(scaling.factor, scaling.mscale)
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+    def table(f):
+        t = f(angles) * m if m != 1.0 else f(angles)
+        return t[..., :, None, :].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 

@@ -3,7 +3,8 @@
 One init / forward / decode implementation parameterized by ``ModelConfig``:
 
 * dense / vlm:  [attn + mlp] x L, scanned, optional remat
-* moe:          [attn(+MLA) + moe] x L, scanned
+* moe:          [attn(+MLA) + mlp] x first_dense_layers, then
+                [attn(+MLA) + moe] x the rest, each stack scanned
 * ssm (rwkv6):  [time-mix + channel-mix] x L, scanned
 * hybrid:       unrolled (rec|attn pattern) blocks + mlp each
 * audio:        encoder (bidirectional) + decoder (causal + cross) stacks
@@ -86,8 +87,9 @@ def apply_block(p, x: jnp.ndarray, cfg: ModelConfig,
                 cache: Optional[Any] = None, pos: Optional[jnp.ndarray] = None,
                 enc_out: Optional[jnp.ndarray] = None,
                 cross_cache: Optional[Any] = None,
-                ) -> Tuple[jnp.ndarray, Optional[Any]]:
-    """One residual block.  Returns (x, new_cache)."""
+                ) -> Tuple[jnp.ndarray, Optional[Any], Optional[Any]]:
+    """One residual block.  Returns (x, new_cache, aux): ``aux`` is an
+    expert block's ``{"balance", "load"}`` (``moe.apply_moe``), else None."""
     x = constrain(x, rules, "batch", "seq", "act_embed")
     h = L.apply_norm(p["norm1"], x, cfg)
     new_cache = cache
@@ -105,7 +107,7 @@ def apply_block(p, x: jnp.ndarray, cfg: ModelConfig,
         x = x + h2
         if decode:
             new_cache = {**tm_state, **cm_state}
-        return x, new_cache
+        return x, new_cache, None
 
     if kind == "rec":
         h, rec_state = G.apply_rglru_block(
@@ -145,12 +147,13 @@ def apply_block(p, x: jnp.ndarray, cfg: ModelConfig,
         x = x + hx
 
     h2 = L.apply_norm(p["norm2"], x, cfg)
+    aux = None
     if kind == "moe":
-        h2 = M.apply_moe(p["mlp"], h2, cfg, rules, impl=moe_impl,
-                         groups=moe_groups)
+        h2, aux = M.apply_moe(p["mlp"], h2, cfg, rules, impl=moe_impl,
+                              groups=moe_groups)
     else:
         h2 = L.apply_mlp(p["mlp"], h2, cfg, rules)
-    return x + h2, new_cache
+    return x + h2, new_cache, aux
 
 
 def _cross_kv(p, enc_out: jnp.ndarray, cfg: ModelConfig, enc_pos):
@@ -186,9 +189,14 @@ def init_lm(cfg: ModelConfig, key) -> Tuple[Any, Any]:
             jax.vmap(lambda k: init_cross_block(cfg, k))(dkeys))
     else:
         kind = _block_kind(cfg, 0)
-        lkeys = jax.random.split(keys[1], cfg.num_layers)
+        n0 = cfg.first_dense_layers
+        lkeys = jax.random.split(keys[1], cfg.num_layers - n0)
         tree["layers"] = L.relabel_stacked(
             jax.vmap(lambda k: init_block(cfg, k, kind))(lkeys))
+        if n0:
+            dkeys = jax.random.split(keys[3], n0)
+            tree["dense_layers"] = L.relabel_stacked(
+                jax.vmap(lambda k: init_block(cfg, k, "dense"))(dkeys))
 
     tree["final_norm"] = L.init_norm(cfg, cfg.d_model)
     return L.split_tree(tree)
@@ -198,25 +206,30 @@ def init_lm(cfg: ModelConfig, key) -> Tuple[Any, Any]:
 # Full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 
-def _scan_stack(stacked_params, x, fn, remat: bool, collect=False):
-    """Scan a homogeneous layer stack.  fn(lp, x) -> (x, aux)."""
+def _scan_stack(stacked_params, x, fn, remat: bool):
+    """Scan a homogeneous layer stack.  fn(lp, x) -> (x, aux); returns the
+    last x and the layers' stacked aux."""
     if remat:
         fn = jax.checkpoint(fn)
+    return jax.lax.scan(lambda carry, lp: fn(lp, carry), x, stacked_params)
 
-    def body(carry, lp):
-        y, aux = fn(lp, carry)
-        return y, (aux if collect else None)
 
-    x, auxs = jax.lax.scan(body, x, stacked_params)
-    return x, auxs
+def _stacks(cfg: ModelConfig):
+    """The scanned stacks of a homogeneous model in order, as (params key,
+    block kind): the dense prefix first where the config has one."""
+    head = [("dense_layers", "dense")] if cfg.first_dense_layers else []
+    return head + [("layers", _block_kind(cfg, 0))]
 
 
 def lm_forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
                rules: Optional[AxisRules] = None, *, impl: str = "auto",
                moe_impl: str = "auto", rec_impl: str = "auto",
-               moe_groups: int = 1, collect_cache: bool = False):
+               moe_groups: int = 1, collect_cache: bool = False,
+               return_aux: bool = False):
     """Returns logits (B, S, V) (decoder logits for enc-dec), and optionally
-    the prefill cache."""
+    the prefill cache.  ``return_aux`` (homogeneous stacks) returns
+    ``(logits, aux)``: the expert layers' stacked ``{"balance", "load"}``,
+    None for a model without them."""
     dt = _dtype(cfg)
     emb = params["embedding"]
 
@@ -241,26 +254,26 @@ def lm_forward(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
                 positions=positions, impl=impl, rec_impl=rec_impl)
             if cfg.remat:
                 fn = jax.checkpoint(fn)
-            x, c = fn(bp, x)
+            x, c, _ = fn(bp, x)
             if collect_cache:
                 caches.append(_prefill_block_cache(bp, x, cfg, kind))
         x = L.apply_norm(params["final_norm"], x, cfg)
         logits = L.unembed(emb, x, cfg, rules)
         return (logits, caches) if collect_cache else logits
 
-    kind = _block_kind(cfg, 0)
+    aux = None
+    for name, kind in _stacks(cfg):
+        def layer_fn(lp, x, kind=kind):
+            y, _, a = apply_block(lp, x, cfg, rules, kind=kind,
+                                  positions=positions, impl=impl,
+                                  moe_impl=moe_impl, rec_impl=rec_impl,
+                                  moe_groups=moe_groups)
+            return y, a
 
-    def layer_fn(lp, x):
-        y, _ = apply_block(lp, x, cfg, rules, kind=kind, positions=positions,
-                           impl=impl, moe_impl=moe_impl, rec_impl=rec_impl,
-                           moe_groups=moe_groups)
-        aux = None
-        return y, aux
-
-    x, _ = _scan_stack(params["layers"], x, layer_fn, cfg.remat)
+        x, aux = _scan_stack(params[name], x, layer_fn, cfg.remat)
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(emb, x, cfg, rules)
-    return logits
+    return (logits, aux) if return_aux else logits
 
 
 def _encdec_forward(params, batch, cfg: ModelConfig, rules, *, impl,
@@ -271,8 +284,8 @@ def _encdec_forward(params, batch, cfg: ModelConfig, rules, *, impl,
     x = frames + L.sinusoidal_positions(frames.shape[1], cfg.d_model).astype(dt)
 
     def enc_fn(lp, x):
-        y, _ = apply_block(lp, x, cfg, rules, kind="dense",
-                           positions=enc_pos, impl=impl, causal=False)
+        y, _, _ = apply_block(lp, x, cfg, rules, kind="dense",
+                              positions=enc_pos, impl=impl, causal=False)
         return y, None
 
     enc_out, _ = _scan_stack(params["encoder"], x, enc_fn, cfg.remat)
@@ -283,8 +296,8 @@ def _encdec_forward(params, batch, cfg: ModelConfig, rules, *, impl,
     y = y + L.sinusoidal_positions(tokens.shape[1], cfg.d_model).astype(dt)
 
     def dec_fn(lp, y):
-        z, _ = apply_block(lp, y, cfg, rules, kind="dense",
-                           positions=dec_pos, impl=impl, enc_out=enc_out)
+        z, _, _ = apply_block(lp, y, cfg, rules, kind="dense",
+                              positions=dec_pos, impl=impl, enc_out=enc_out)
         return z, None
 
     y, _ = _scan_stack(params["decoder"], y, dec_fn, cfg.remat)
@@ -340,13 +353,28 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 def lm_loss(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
-            rules: Optional[AxisRules] = None, **fw) -> jnp.ndarray:
-    logits = lm_forward(params, batch, cfg, rules, **fw)
+            rules: Optional[AxisRules] = None, *, with_aux: bool = False,
+            **fw):
+    """Mean next-token cross entropy; an expert model adds its layers'
+    balance-loss terms.  ``with_aux`` returns ``(loss, aux)``, ``aux`` the
+    summed ``"balance"`` term and ``"load"``, the assignments each expert
+    layer's held experts received, (expert layers, held); None without
+    expert layers."""
+    aux = None
+    if cfg.moe is None:
+        logits = lm_forward(params, batch, cfg, rules, **fw)
+    else:
+        logits, aux = lm_forward(params, batch, cfg, rules, return_aux=True,
+                                 **fw)
+        aux = {"balance": jnp.sum(aux["balance"]), "load": aux["load"]}
     targets = batch["targets"]
     # frontend positions prepend to the sequence; align targets to the tail
     if logits.shape[1] != targets.shape[1]:
         logits = logits[:, -targets.shape[1]:]
-    return _fused_ce(logits, targets)
+    loss = _fused_ce(logits, targets)
+    if aux is not None:
+        loss = loss + aux["balance"]
+    return (loss, aux) if with_aux else loss
 
 
 # ---------------------------------------------------------------------------
@@ -379,13 +407,20 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *,
             "v": jnp.zeros((Ld, batch, enc_len, cfg.num_kv_heads, hd), dtype),
         }
         return {"self": stacked_self, "cross": cross}
-    if cfg.mla is not None:
-        caches = [A.init_mla_cache(cfg, batch, max_len, dtype)
-                  for _ in range(cfg.num_layers)]
+
+    def stack(n):
+        if cfg.mla is not None:
+            caches = [A.init_mla_cache(cfg, batch, max_len, dtype)
+                      for _ in range(n)]
+        else:
+            caches = [A.init_kv_cache(cfg, batch, max_len, dtype=dtype)
+                      for _ in range(n)]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-    caches = [A.init_kv_cache(cfg, batch, max_len, dtype=dtype)
-              for _ in range(cfg.num_layers)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+
+    n0 = cfg.first_dense_layers
+    if n0:   # one stacked cache per scanned stack
+        return {"dense_layers": stack(n0), "layers": stack(cfg.num_layers - n0)}
+    return stack(cfg.num_layers)
 
 
 def decode_step(params, cache: Any, tokens: jnp.ndarray, pos: jnp.ndarray,
@@ -403,9 +438,9 @@ def decode_step(params, cache: Any, tokens: jnp.ndarray, pos: jnp.ndarray,
         new_caches = []
         for i, bp in enumerate(params["blocks"]):
             kind = _block_kind(cfg, i)
-            x, nc = apply_block(bp, x, cfg, rules, kind=kind,
-                                positions=pos[None], impl=impl,
-                                cache=cache[i], pos=pos)
+            x, nc, _ = apply_block(bp, x, cfg, rules, kind=kind,
+                                   positions=pos[None], impl=impl,
+                                   cache=cache[i], pos=pos)
             new_caches.append(nc)
         x = L.apply_norm(params["final_norm"], x, cfg)
         return L.unembed(emb, x, cfg, rules), new_caches
@@ -413,9 +448,10 @@ def decode_step(params, cache: Any, tokens: jnp.ndarray, pos: jnp.ndarray,
     if cfg.is_encoder_decoder:
         def body(x, inp):
             lp, lself, lck, lcv = inp
-            y, nc = apply_block(lp, x, cfg, rules, kind="dense",
-                                positions=pos[None], impl=impl,
-                                cache=lself, pos=pos, cross_cache=(lck, lcv))
+            y, nc, _ = apply_block(lp, x, cfg, rules, kind="dense",
+                                   positions=pos[None], impl=impl,
+                                   cache=lself, pos=pos,
+                                   cross_cache=(lck, lcv))
             return y, nc
         x, new_self = jax.lax.scan(
             body, x, (params["decoder"], cache["self"],
@@ -424,17 +460,30 @@ def decode_step(params, cache: Any, tokens: jnp.ndarray, pos: jnp.ndarray,
         return L.unembed(emb, x, cfg, rules), {"self": new_self,
                                                "cross": cache["cross"]}
 
-    kind = _block_kind(cfg, 0)
+    def scan(x, name, kind, stack_cache):
+        def body(x, inp):
+            lp, lcache = inp
+            y, nc, _ = apply_block(lp, x, cfg, rules, kind=kind,
+                                   positions=pos[None], impl=impl,
+                                   moe_impl=moe_impl, cache=lcache, pos=pos)
+            return y, nc
+        return jax.lax.scan(body, x, (params[name], stack_cache))
 
-    def body(x, inp):
-        lp, lcache = inp
-        y, nc = apply_block(lp, x, cfg, rules, kind=kind, positions=pos[None],
-                            impl=impl, moe_impl=moe_impl, cache=lcache, pos=pos)
-        return y, nc
-
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+    x, new_cache = _scan_stacks_cached(cfg, x, cache, scan)
     x = L.apply_norm(params["final_norm"], x, cfg)
     return L.unembed(emb, x, cfg, rules), new_cache
+
+
+def _scan_stacks_cached(cfg: ModelConfig, x, cache, scan):
+    """Run ``scan(x, params key, kind, stack cache) -> (x, new stack cache)``
+    over the model's stacks; the cache is one stacked tree, or with a dense
+    prefix one per stack (``init_cache``)."""
+    if not cfg.first_dense_layers:
+        return scan(x, "layers", _block_kind(cfg, 0), cache)
+    new_cache = {}
+    for name, kind in _stacks(cfg):
+        x, new_cache[name] = scan(x, name, kind, cache[name])
+    return x, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -457,8 +506,8 @@ def prefill_step(params, cache: Any, batch: Dict[str, jnp.ndarray],
         x = frames + L.sinusoidal_positions(frames.shape[1], cfg.d_model).astype(dt)
 
         def enc_fn(lp, x):
-            y, _ = apply_block(lp, x, cfg, rules, kind="dense",
-                               positions=enc_pos, impl=impl, causal=False)
+            y, _, _ = apply_block(lp, x, cfg, rules, kind="dense",
+                                  positions=enc_pos, impl=impl, causal=False)
             return y, None
 
         enc_out, _ = _scan_stack(params["encoder"], x, enc_fn, cfg.remat)
@@ -485,26 +534,26 @@ def prefill_step(params, cache: Any, batch: Dict[str, jnp.ndarray],
         new_caches = []
         for i, bp in enumerate(params["blocks"]):
             kind = _block_kind(cfg, i)
-            x, nc = apply_block(bp, x, cfg, rules, kind=kind,
-                                positions=jnp.arange(x.shape[1]), impl=impl,
-                                cache=cache[i], pos=pos0)
+            x, nc, _ = apply_block(bp, x, cfg, rules, kind=kind,
+                                   positions=jnp.arange(x.shape[1]),
+                                   impl=impl, cache=cache[i], pos=pos0)
             new_caches.append(nc)
         x = L.apply_norm(params["final_norm"], x, cfg)
         logits = L.unembed(emb, x[:, -1:], cfg, rules)
         return logits, new_caches
 
-    kind = _block_kind(cfg, 0)
+    def scan(x, name, kind, stack_cache):
+        def body(x, inp):
+            lp, lcache = inp
+            y, nc, _ = apply_block(lp, x, cfg, rules, kind=kind,
+                                   positions=jnp.arange(x.shape[1]),
+                                   impl=impl, moe_impl=moe_impl, cache=lcache,
+                                   pos=pos0, moe_groups=moe_groups)
+            return y, nc
+        fn = jax.checkpoint(body) if cfg.remat else body
+        return jax.lax.scan(fn, x, (params[name], stack_cache))
 
-    def body(x, inp):
-        lp, lcache = inp
-        y, nc = apply_block(lp, x, cfg, rules, kind=kind,
-                            positions=jnp.arange(x.shape[1]), impl=impl,
-                            moe_impl=moe_impl, cache=lcache, pos=pos0,
-                            moe_groups=moe_groups)
-        return y, nc
-
-    fn = jax.checkpoint(body) if cfg.remat else body
-    x, new_cache = jax.lax.scan(fn, x, (params["layers"], cache))
+    x, new_cache = _scan_stacks_cached(cfg, x, cache, scan)
     x = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(emb, x[:, -1:], cfg, rules)
     return logits, new_cache

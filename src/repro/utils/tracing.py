@@ -36,6 +36,15 @@ MERGE = "hermes/merge"      # decode and loss-weighted merge
 REFRESH = "hermes/refresh"  # pushing pods take the global model
 SCOPES = (GATE, ENCODE, GATHER, MERGE, REFRESH)
 
+# Device phases of the training step's expert and latent-attention layers
+# (``models/moe.py: moe_dropless``, ``models/attention.py: apply_mla``).
+ROUTE = "moe/route"          # router logits, softmax, top-k, balance loss
+DISPATCH = "moe/dispatch"    # sort by held expert, gather, scatter-combine
+EXPERTS = "moe/experts"      # grouped products over the held experts
+SHARED = "moe/shared"        # the shared experts, once per token
+MLA = "mla/attend"           # MLA projections, rope, attention, output
+STEP_SCOPES = (ROUTE, DISPATCH, EXPERTS, SHARED, MLA)
+
 _open_gc = []
 
 

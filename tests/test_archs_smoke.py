@@ -102,7 +102,7 @@ def test_full_configs_match_brief():
         "llava-next-34b": (60, 7168, 20480, 64000),
         "seamless-m4t-large-v2": (24, 1024, 8192, 256206),
         "grok-1-314b": (64, 6144, 32768, 131072),
-        "deepseek-v2-lite-16b": (27, 2048, 1408, 102400),
+        "deepseek-v2-lite-16b": (27, 2048, 10944, 102400),  # dense layer
         "recurrentgemma-2b": (26, 2560, 7680, 256000),
     }
     for arch, (L, d, ff, v) in expect.items():
@@ -114,6 +114,9 @@ def test_full_configs_match_brief():
     assert get_config("grok-1-314b").moe.top_k == 2
     dsv2 = get_config("deepseek-v2-lite-16b")
     assert dsv2.moe.num_experts == 64 and dsv2.moe.top_k == 6
+    assert dsv2.moe.expert_ff == 1408 and dsv2.first_dense_layers == 1
+    assert not dsv2.moe.norm_topk_prob and dsv2.moe.dropless
+    assert dsv2.rope_scaling.factor == 40
     assert dsv2.mla.kv_lora_rank == 512
     rg = get_config("recurrentgemma-2b")
     assert rg.recurrent.block_pattern == ("rec", "rec", "attn")

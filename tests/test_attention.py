@@ -24,6 +24,29 @@ def test_blocked_matches_naive(B, Sq, Skv, H, K, D, Dv, causal):
     np.testing.assert_allclose(o1, o2, atol=2e-5)
 
 
+@pytest.mark.parametrize("max_bytes", [0, 1 << 30])
+def test_blocked_gradients_match_naive(max_bytes, monkeypatch):
+    """Past ``SAVED_SCORES_MAX_BYTES`` the backward pass recomputes each
+    chunk's scores; below it keeps them.  Both give naive's gradients."""
+    from repro.models import attention as A
+    monkeypatch.setattr(A, "SAVED_SCORES_MAX_BYTES", max_bytes)
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(ks[0], (2, 40, 4, 24))
+    k = jax.random.normal(ks[1], (2, 40, 4, 24))
+    v = jax.random.normal(ks[2], (2, 40, 4, 16))
+    ct = jax.random.normal(ks[3], (2, 40, 4, 16))
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * ct),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    g1 = grads(lambda q, k, v: blocked_attention(q, k, v, causal=True,
+                                                 q_chunk=16, kv_chunk=16))
+    g2 = grads(lambda q, k, v: naive_attention(q, k, v, causal=True))
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+
+
 @pytest.mark.parametrize("window", [1, 4, 16, 100])
 def test_window_matches_naive(window):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
